@@ -50,8 +50,8 @@ def _decode_attention_section() -> None:
     B, Kv, G, hd, page, P = 8, 8, 4, 128, 16, 64      # 1024-token context
     N = B * P + 1
     key = jax.random.PRNGKey(0)
-    kp = jax.random.normal(key, (N, page, Kv, hd), jnp.float32)
-    vp = jax.random.normal(key, (N, page, Kv, hd), jnp.float32)
+    kp = jax.random.normal(key, (N, Kv, page, hd), jnp.float32)
+    vp = jax.random.normal(key, (N, Kv, page, hd), jnp.float32)
     q = jax.random.normal(key, (B, Kv, G, hd), jnp.float32)
     tables = jnp.arange(1, N, dtype=jnp.int32).reshape(B, P)
     lengths = jnp.full((B,), P * page, jnp.int32)

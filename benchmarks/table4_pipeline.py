@@ -40,7 +40,8 @@ SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from repro.core.pipeline import pipeline_apply
     P, M, D, B = 4, 16, 256, 8
-    mesh = jax.make_mesh((P,), ("pipe",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((P,), ("pipe",))
     rng = np.random.RandomState(0)
     sp = {"w": jnp.asarray(rng.randn(P, D, D) * 0.1, jnp.float32)}
     mbs = jnp.asarray(rng.randn(M, B, D), jnp.float32)

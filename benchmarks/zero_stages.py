@@ -45,7 +45,8 @@ SCRIPT = textwrap.dedent(
     from repro.train import TrainConfig
     from repro.roofline.analysis import collective_bytes
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2), ("data", "model"))
     shape = ShapeSpec("bench", 128, 8, "train")
     cfg_name = "granite-8b"
     import repro.launch.train as LT

@@ -13,9 +13,11 @@ import sys
 import textwrap
 
 def _subprocess_env():
-    """Inherit the environment (JAX_PLATFORMS etc. — a bare env hangs jax
-    backend probing on CPU containers); scripts set their own XLA_FLAGS."""
-    env = dict(os.environ, PYTHONPATH="src")
+    """Inherit the environment (a bare env hangs jax backend probing on CPU
+    containers), pinned to the CPU: the children fake host devices, and a
+    child that reached for the TPU would fight its parent for the chip.
+    Scripts set their own XLA_FLAGS."""
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     return env
 
@@ -35,7 +37,8 @@ SCRIPT = textwrap.dedent(
 
     cfg = get_reduced("granite-8b")
     registry.ARCHITECTURES[cfg.name] = cfg
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     shape = ShapeSpec("demo", 64, 16, "train")
 
     data = DataPipeline(cfg, shape.global_batch, shape.seq_len, seed=0)

@@ -12,4 +12,5 @@ from repro.configs.registry import (  # noqa: F401
     get_config,
     get_reduced,
     get_shape,
+    launch_config,
 )

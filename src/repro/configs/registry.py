@@ -1,6 +1,7 @@
 """Registry of assigned architectures (+ the survey's own demo config)."""
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List
 
 from repro.configs.base import ArchConfig, INPUT_SHAPES, ShapeSpec, reduced
@@ -85,3 +86,26 @@ def get_shape(name: str) -> ShapeSpec:
 
 def get_reduced(name: str, **over) -> ArchConfig:
     return reduced(get_config(name), **over)
+
+
+def launch_config(name: str, *, full: bool = False, layers: int = 0) -> ArchConfig:
+    """The config a launcher runs, registered under its own name so the
+    ``launch.train.build_*`` helpers, which look configs up by name, find it.
+
+    By default the ``reduced()`` smoke size; ``full`` keeps the published
+    widths. ``layers`` cuts the depth to that many layers, which must be
+    whole periods of the layer pattern; widths are untouched.
+    """
+    cfg = get_config(name) if full else get_reduced(name)
+    if layers:
+        period = len(cfg.pattern)
+        if layers % period:
+            raise ValueError(
+                f"{name}: --layers {layers} is not a whole number of "
+                f"{period}-layer pattern periods"
+            )
+        cfg = dataclasses.replace(
+            cfg, name=f"{cfg.name}-{layers}L", n_layers=layers
+        )
+    ARCHITECTURES[cfg.name] = cfg
+    return cfg

@@ -32,8 +32,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 MIN_SIZE = 1024
 
 
@@ -121,7 +119,7 @@ def _topk_sync(method: TopK, g: jax.Array, ef, axis_name):
     if axis_name is None:
         mean = ghat_local
     else:
-        n = compat.axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         all_idx = jax.lax.all_gather(idx, axis_name)       # (N, k)
         all_val = jax.lax.all_gather(vals, axis_name)
         mean = (

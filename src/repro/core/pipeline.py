@@ -384,8 +384,7 @@ def pipeline_apply(
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as Pspec
-
-    from repro.compat import shard_map
+    from jax import shard_map
 
     P_count = mesh.shape[axis]
     x0 = jax.tree.map(lambda m: m[0], microbatches)
@@ -654,8 +653,8 @@ def pipeline_grads(
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as Pspec
+    from jax import shard_map
 
-    from repro.compat import shard_map
     from repro.core.stash import RawStash
 
     backend = stash if stash is not None else RawStash()

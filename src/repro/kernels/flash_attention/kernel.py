@@ -110,7 +110,7 @@ def _fa_fwd_kernel(
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[...] + jnp.log(denom)
+        lse_ref[0, 0] = m_scr[...] + jnp.log(denom)
 
 
 @functools.partial(
@@ -129,7 +129,11 @@ def flash_attention_fwd_flat(
     block_k: int = 128,
     interpret=None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Forward with saved stats. Returns (o (BH, S, hd), lse (BH, S) f32)."""
+    """Forward with saved stats. Returns (o (BH, S, hd), lse (BH, 1, S) f32).
+
+    ``lse`` keeps a unit axis so its (1, block_q) block is a whole-array
+    second-minor dim, as the TPU block rule asks.
+    """
     interpret = resolve_interpret(interpret)
     BH, S, hd = q.shape
     Sk = k.shape[1]
@@ -154,11 +158,11 @@ def flash_attention_fwd_flat(
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, hd), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, block_q), lambda h, i, j: (h, i)),
+            pl.BlockSpec((1, 1, block_q), lambda h, i, j: (h, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sp, hd), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Sp), jnp.float32),
         ],
         scratch_shapes=[
             _vmem((block_q,), jnp.float32),
@@ -167,7 +171,7 @@ def flash_attention_fwd_flat(
         ],
         interpret=interpret,
     )(q, k, v)
-    return o[:, :S], lse[:, :S]
+    return o[:, :S], lse[:, :, :S]
 
 
 @functools.partial(

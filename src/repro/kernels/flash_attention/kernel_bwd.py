@@ -43,7 +43,7 @@ from repro.kernels.runtime import resolve_interpret
 def _fa_bwd_delta_kernel(o_ref, do_ref, delta_ref):
     o = o_ref[0].astype(jnp.float32)              # (bq, hd)
     do = do_ref[0].astype(jnp.float32)
-    delta_ref[0] = jnp.sum(o * do, axis=-1)
+    delta_ref[0, 0] = jnp.sum(o * do, axis=-1)
 
 
 def _fa_bwd_dq_kernel(
@@ -62,8 +62,8 @@ def _fa_bwd_dq_kernel(
     k = k_ref[0].astype(jnp.float32)              # (bk, hd)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)            # (bq, hd)
-    lse = lse_ref[0]                              # (bq,)
-    delta = delta_ref[0]                          # (bq,)
+    lse = lse_ref[0, 0]                           # (bq,)
+    delta = delta_ref[0, 0]                       # (bq,)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -101,8 +101,8 @@ def _fa_bwd_dkv_kernel(
 
     q = q_ref[0].astype(jnp.float32)              # (bq, hd)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                              # (bq,)
-    delta = delta_ref[0]
+    lse = lse_ref[0, 0]                           # (bq,)
+    delta = delta_ref[0, 0]
     k = k_ref[0].astype(jnp.float32)              # (bk, hd)
     v = v_ref[0].astype(jnp.float32)
 
@@ -142,7 +142,7 @@ def flash_attention_bwd_flat(
     k: jax.Array,    # (BKv, Sk, hd)
     v: jax.Array,
     o: jax.Array,    # (BH, S, hd) forward output
-    lse: jax.Array,  # (BH, S) f32 forward stats
+    lse: jax.Array,  # (BH, 1, S) f32 forward stats
     do: jax.Array,   # (BH, S, hd) upstream cotangent
     *,
     group: int,
@@ -162,7 +162,7 @@ def flash_attention_bwd_flat(
     q = pad_seq(q, block_q)
     o = pad_seq(o, block_q)
     do = pad_seq(do, block_q)
-    lse_p = jnp.pad(lse, ((0, 0), (0, q.shape[1] - S)))
+    lse_p = jnp.pad(lse, ((0, 0), (0, 0), (0, q.shape[1] - S)))
     k = pad_seq(k, block_k)
     v = pad_seq(v, block_k)
     Sp, Skp = q.shape[1], k.shape[1]
@@ -176,8 +176,8 @@ def flash_attention_bwd_flat(
             pl.BlockSpec((1, block_q, hd), lambda h, i: (h, i, 0)),
             pl.BlockSpec((1, block_q, hd), lambda h, i: (h, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q), lambda h, i: (h, i)),
-        out_shape=jax.ShapeDtypeStruct((BH, Sp), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_q), lambda h, i: (h, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((BH, 1, Sp), jnp.float32),
         interpret=interpret,
     )(o, do)
 
@@ -193,8 +193,8 @@ def flash_attention_bwd_flat(
             pl.BlockSpec((1, block_k, hd), lambda h, i, j, g=group: (h // g, j, 0)),
             pl.BlockSpec((1, block_k, hd), lambda h, i, j, g=group: (h // g, j, 0)),
             pl.BlockSpec((1, block_q, hd), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, block_q), lambda h, i, j: (h, i)),
-            pl.BlockSpec((1, block_q), lambda h, i, j: (h, i)),
+            pl.BlockSpec((1, 1, block_q), lambda h, i, j: (h, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda h, i, j: (h, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sp, hd), q.dtype),
@@ -226,10 +226,12 @@ def flash_attention_bwd_flat(
                 lambda h, jk, t, nq=n_q: (_qh(h, t), t % nq, 0),
             ),
             pl.BlockSpec(
-                (1, block_q), lambda h, jk, t, nq=n_q: (_qh(h, t), t % nq)
+                (1, 1, block_q),
+                lambda h, jk, t, nq=n_q: (_qh(h, t), 0, t % nq),
             ),
             pl.BlockSpec(
-                (1, block_q), lambda h, jk, t, nq=n_q: (_qh(h, t), t % nq)
+                (1, 1, block_q),
+                lambda h, jk, t, nq=n_q: (_qh(h, t), 0, t % nq),
             ),
             pl.BlockSpec((1, block_k, hd), lambda h, jk, t: (h, jk, 0)),
             pl.BlockSpec((1, block_k, hd), lambda h, jk, t: (h, jk, 0)),

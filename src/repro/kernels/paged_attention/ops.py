@@ -1,12 +1,14 @@
-"""Public paged decode-attention op + page-layout helpers.
+"""Public paged-attention ops: chunked prefill, and decode as its T=1 call.
 
-``paged_attention`` routes between the Pallas kernel (``kernel.py``) and the
-pure-jnp oracle (``ref.py``); the kernel is the TPU path, the oracle doubles
-as the fast CPU path (interpret-mode Pallas inside a decode scan is far
-slower than one gather + einsum). Both share the exact layout contract
-documented in ``ref.py``.
+``paged_prefill_attention`` routes between the Pallas kernel (``kernel.py``)
+and the pure-jnp oracle (``ref.py``); the kernel is the TPU path, the oracle
+doubles as the fast CPU path (interpret-mode Pallas inside a decode scan is
+far slower than one gather + einsum). Both share the exact layout contract
+documented in ``ref.py``. ``paged_attention`` (decode) is the T=1 call of
+the same op, so a decode step and a one-row verify or prefill chunk run the
+same program, bit for bit.
 
-Quantized pools: pass ``k_scale``/``v_scale`` (N, page, Kv) f32 and both
+Quantized pools: pass ``k_scale``/``v_scale`` ([L,] N, Kv, page) f32 and both
 backends dequantize inside the page gather (see ``quant.py``); the scale
 operands shard over the same ``model`` kv-head axis as their pools.
 
@@ -21,21 +23,24 @@ row-sharded ``wo`` matmul downstream carries the reduce.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-from repro.kernels.paged_attention.kernel import (
-    paged_attention_kernel,
-    paged_prefill_attention_kernel,
-)
-from repro.kernels.paged_attention.ref import (
-    paged_attention_ref,
-    paged_prefill_attention_ref,
-)
+from repro.kernels.paged_attention.kernel import paged_prefill_attention_kernel
+from repro.kernels.paged_attention.ref import paged_prefill_attention_ref
 
 
 def tp_size(mesh) -> int:
     return mesh.shape["model"] if mesh is not None and "model" in mesh.shape else 1
+
+
+def _pool_specs(k_pages, quantized):
+    """shard_map specs of the pool (and scale) operands: the kv-head axis
+    (dim -3 of ``([L,] N, Kv, page, hd)``) over ``model``."""
+    lead = (None,) * (k_pages.ndim - 3)
+    pool = P(*lead, "model", None, None)
+    return (pool, pool) + ((P(*lead, "model", None),) * 2 if quantized else ())
 
 
 def paged_attention(
@@ -44,50 +49,18 @@ def paged_attention(
     v_pages: jax.Array,
     tables: jax.Array,
     lengths: jax.Array,
-    *,
-    window: int = 0,
-    use_kernel: bool = True,
-    interpret=None,
-    mesh=None,
-    k_scale=None,
-    v_scale=None,
+    **kw,
 ) -> jax.Array:
-    """q: (B, Kv, G, hd) pre-scaled; pools (N, page, Kv, hd) -> (B, Kv, G, hd)."""
-    quantized = k_scale is not None
-
-    def attend(q_, kp_, vp_, tbl_, ln_, *sc_):
-        ks_, vs_ = sc_ if quantized else (None, None)
-        if use_kernel:
-            return paged_attention_kernel(
-                q_, kp_, vp_, tbl_, ln_, window=window, interpret=interpret,
-                k_scale=ks_, v_scale=vs_,
-            )
-        return paged_attention_ref(
-            q_, kp_, vp_, tbl_, ln_, window=window, k_scale=ks_, v_scale=vs_
-        )
-
-    args = (q, k_pages, v_pages, tables, lengths)
-    if quantized:
-        args = args + (k_scale, v_scale)
-    tp = tp_size(mesh)
-    if tp > 1 and q.shape[1] % tp == 0:
-        # per-shard head slices: the kernel grid sees Kv/tp program rows,
-        # gathering from a pool that only stores those heads' pages
-        head = P(None, "model", None, None)
-        pool = P(None, None, "model", None)
-        in_specs = (head, pool, pool, P(None, None), P(None))
-        if quantized:
-            scale = P(None, None, "model")
-            in_specs = in_specs + (scale, scale)
-        fn = shard_map(
-            attend,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=head,
-            check_vma=False,
-        )
-        return fn(*args)
-    return attend(*args)
+    """Decode attention: the T=1 call of :func:`paged_prefill_attention`,
+    one query per slot at position ``lengths - 1``. q: (B, Kv, G, hd)
+    pre-scaled; pools ([L,] N, Kv, page, hd) -> (B, Kv, G, hd). Keyword
+    arguments as for :func:`paged_prefill_attention`."""
+    lengths = lengths.astype(jnp.int32)
+    out = paged_prefill_attention(
+        q[:, None], k_pages, v_pages, tables, lengths - 1,
+        jnp.ones_like(lengths), **kw,
+    )
+    return out[:, 0]
 
 
 def paged_prefill_attention(
@@ -104,12 +77,14 @@ def paged_prefill_attention(
     mesh=None,
     k_scale=None,
     v_scale=None,
+    layer=None,
 ) -> jax.Array:
     """Chunked-prefill attention over pool pages.
 
     q: (B, T, Kv, G, hd) pre-scaled, roped at ``start + t``; pools
-    (N, page, Kv, hd); per-request ``start`` (absolute position of row 0)
-    and ``q_len`` (valid rows). Returns (B, T, Kv, G, hd).
+    ([L,] N, Kv, page, hd), read at ``layer`` when stacked; per-request
+    ``start`` (absolute position of row 0) and ``q_len`` (valid rows).
+    Returns (B, T, Kv, G, hd).
 
     Same tensor-parallel contract as :func:`paged_attention`: the kv-head
     axis shards over ``model`` (q axis 2 here), tables / positions stay
@@ -122,30 +97,28 @@ def paged_prefill_attention(
     prefill correctness, no separate masking code path.
     """
     quantized = k_scale is not None
+    layer = jnp.int32(0) if layer is None else layer
+    stacked = k_pages.ndim == 5
 
-    def attend(q_, kp_, vp_, tbl_, st_, ln_, *sc_):
+    def attend(q_, ly_, tbl_, st_, ln_, kp_, vp_, *sc_):
         ks_, vs_ = sc_ if quantized else (None, None)
+        kw = dict(window=window, k_scale=ks_, v_scale=vs_,
+                  layer=ly_ if stacked else None)
         if use_kernel:
             return paged_prefill_attention_kernel(
-                q_, kp_, vp_, tbl_, st_, ln_, window=window,
-                interpret=interpret, k_scale=ks_, v_scale=vs_,
+                q_, kp_, vp_, tbl_, st_, ln_, interpret=interpret, **kw
             )
-        return paged_prefill_attention_ref(
-            q_, kp_, vp_, tbl_, st_, ln_, window=window,
-            k_scale=ks_, v_scale=vs_,
-        )
+        return paged_prefill_attention_ref(q_, kp_, vp_, tbl_, st_, ln_, **kw)
 
-    args = (q, k_pages, v_pages, tables, start, q_len)
+    args = (q, layer, tables, start, q_len, k_pages, v_pages)
     if quantized:
         args = args + (k_scale, v_scale)
     tp = tp_size(mesh)
     if tp > 1 and q.shape[2] % tp == 0:
         head = P(None, None, "model", None, None)
-        pool = P(None, None, "model", None)
-        in_specs = (head, pool, pool, P(None, None), P(None), P(None))
-        if quantized:
-            scale = P(None, None, "model")
-            in_specs = in_specs + (scale, scale)
+        in_specs = (head, P(), P(None, None), P(None), P(None)) + _pool_specs(
+            k_pages, quantized
+        )
         fn = shard_map(
             attend,
             mesh=mesh,

@@ -17,7 +17,7 @@ block-table index_map as the page itself, and the jnp oracles gather
 scales through ``tables`` alongside the pools — no dequantized pool is
 ever materialized.
 
-Scale layout: pools (N, page, Kv, hd) carry scales (N, page, Kv) f32.
+Scale layout: pools (N, Kv, page, hd) carry scales (N, Kv, page) f32.
 Per-token bytes go from ``2 * Kv * hd * itemsize(native)`` to
 ``2 * Kv * (hd + 4)`` — ~0.53x at bf16/hd=64, i.e. ~1.9x resident
 requests per device at an equal pool-byte budget.

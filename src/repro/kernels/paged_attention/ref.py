@@ -7,8 +7,11 @@ virtualized tensor memory (vDNN-style paging).
 
 Layouts (match ``repro.models.attention`` conventions):
   q        (B, Kv, G, hd)   pre-scaled by hd^-0.5, roped at position L-1
-  k_pages  (N, page, Kv, hd) global pool; page 0 is the reserved null page
-  v_pages  (N, page, Kv, hd)
+  k_pages  (N, Kv, page, hd) global pool, head-major (one kv head's page is
+                            a contiguous tile); page 0 is the reserved null
+                            page. A layer-stacked (L, N, Kv, page, hd) pool
+                            is read at ``layer``.
+  v_pages  (N, Kv, page, hd)
   tables   (B, P) int32      page ids per request (padding entries -> 0)
   lengths  (B,) int32        valid tokens per request (incl. current token)
 
@@ -16,7 +19,7 @@ The oracle gathers the full (B, P*page) key band and masks by absolute
 position, so it is exact for non-page-multiple lengths and sliding windows.
 
 Quantized pools (``kv_dtype`` int8/fp8): ``k_scale``/``v_scale``
-(N, page, Kv) f32 ride along and are gathered through the same block
+(N, Kv, page) f32 ride along and are gathered through the same block
 table, dequantizing the band in f32 right at the gather — the oracle
 counterpart of the kernels' fused in-gather dequant (no dequantized pool
 is materialized beyond the gathered band this oracle builds anyway).
@@ -31,14 +34,18 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _gather_band(pages, tables, S, scale):
-    """(N, page, Kv, hd) pool -> (B, S, Kv, hd) f32 band via the block
-    table, dequantized when ``scale`` (N, page, Kv) is present."""
+def _gather_band(pages, tables, S, scale, layer):
+    """([L,] N, Kv, page, hd) pool -> (B, S, Kv, hd) f32 band via the block
+    table (at ``layer`` of a stacked pool), dequantized when ``scale``
+    ([L,] N, Kv, page) is present."""
     B = tables.shape[0]
-    Kv, hd = pages.shape[2], pages.shape[3]
-    band = pages[tables].reshape(B, S, Kv, hd).astype(jnp.float32)
+    Kv, hd = pages.shape[-3], pages.shape[-1]
+    idx = tables if layer is None else (layer, tables)
+    band = pages[idx].swapaxes(2, 3).reshape(B, S, Kv, hd)
+    band = band.astype(jnp.float32)
     if scale is not None:
-        band = band * scale[tables].reshape(B, S, Kv, 1).astype(jnp.float32)
+        sc = scale[idx].swapaxes(2, 3).reshape(B, S, Kv, 1)
+        band = band * sc.astype(jnp.float32)
     return band
 
 
@@ -52,14 +59,15 @@ def paged_attention_ref(
     window: int = 0,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Returns (B, Kv, G, hd). Query position is ``lengths - 1`` per slot."""
     B, Kv, G, hd = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[-2]
     P = tables.shape[1]
 
-    k = _gather_band(k_pages, tables, P * page, k_scale)
-    v = _gather_band(v_pages, tables, P * page, v_scale)
+    k = _gather_band(k_pages, tables, P * page, k_scale, layer)
+    v = _gather_band(v_pages, tables, P * page, v_scale, layer)
 
     scores = jnp.einsum(
         "bkgh,bskh->bkgs", q.astype(jnp.float32), k,
@@ -87,6 +95,7 @@ def paged_prefill_attention_ref(
     window: int = 0,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Chunked-prefill sibling of :func:`paged_attention_ref`.
 
@@ -106,11 +115,11 @@ def paged_prefill_attention_ref(
     in allocated-but-unwritten pages is never attended by a valid row.
     """
     B, T, Kv, G, hd = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[-2]
     P = tables.shape[1]
 
-    k = _gather_band(k_pages, tables, P * page, k_scale)
-    v = _gather_band(v_pages, tables, P * page, v_scale)
+    k = _gather_band(k_pages, tables, P * page, k_scale, layer)
+    v = _gather_band(v_pages, tables, P * page, v_scale, layer)
     scores = jnp.einsum(
         "btkgh,bskh->bkgts", q.astype(jnp.float32), k,
         preferred_element_type=jnp.float32,

@@ -3,8 +3,10 @@
 Forward — one pass per row tile: mean-of-squares reduce, rsqrt, scale —
 fused so the row is read from HBM once (XLA emits separate reduce + multiply
 kernels when the norm is unfused at the boundary of a remat block). Rows tile
-over the grid; the feature dim stays whole in VMEM (d_model <= 8192 ->
-<= 32 KiB f32 per row, well inside VMEM at TILE_ROWS=256).
+over the grid; the feature dim stays whole in VMEM, and the row tile
+shrinks with ``d`` so an f32 tile stays at TILE_BYTES (256 rows at d=512,
+32 rows at d=4096): the backward holds several such tiles at once, and at
+256 rows x 4096 they overflow the chip's scoped VMEM.
 
 Backward — fused dx/dscale in the same row tiling. With xhat = x * inv and
 gs = g * scale:
@@ -29,6 +31,7 @@ from jax.experimental import pallas as pl
 from repro.kernels.runtime import resolve_interpret
 
 TILE_ROWS = 256
+TILE_BYTES = 512 * 1024     # one f32 (rows, d) tile
 
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -57,8 +60,9 @@ def _rmsnorm_bwd_kernel(x_ref, s_ref, g_ref, dx_ref, ds_ref, *, eps: float):
     ds_ref[...] += jnp.sum(g * xhat, axis=0, keepdims=True)
 
 
-def _tile(rows: int) -> Tuple[int, int]:
-    tile = min(TILE_ROWS, rows)
+def _tile(rows: int, d: int) -> Tuple[int, int]:
+    cap = max(8, min(TILE_ROWS, TILE_BYTES // (4 * d)) // 8 * 8)
+    tile = min(cap, rows)
     pad = (-rows) % tile
     return tile, pad
 
@@ -72,7 +76,7 @@ def rmsnorm_pallas(
     d = orig_shape[-1]
     rows = x.size // d
     xr = x.reshape(rows, d)
-    tile, pad = _tile(rows)
+    tile, pad = _tile(rows, d)
     if pad:
         xr = jnp.pad(xr, ((0, pad), (0, 0)))
     out = pl.pallas_call(
@@ -101,7 +105,7 @@ def rmsnorm_bwd_pallas(
     rows = x.size // d
     xr = x.reshape(rows, d)
     gr = g.reshape(rows, d)
-    tile, pad = _tile(rows)
+    tile, pad = _tile(rows, d)
     if pad:
         # zero rows contribute exact zeros to both dx (sliced off) and dscale
         xr = jnp.pad(xr, ((0, pad), (0, 0)))

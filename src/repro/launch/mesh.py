@@ -7,6 +7,7 @@ dry-run must set XLA_FLAGS before that happens.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e per-chip constants (used by repro.roofline)
 PEAK_FLOPS_BF16 = 197e12       # FLOP/s
@@ -14,16 +15,32 @@ HBM_BW = 819e9                 # bytes/s
 ICI_BW = 50e9                  # bytes/s per link
 
 
+def make_mesh(shape, axes, *, devices=None):
+    """Every mesh of the repo is built here, with ``Auto`` axes.
+
+    The code states its shardings with ``NamedSharding``/``shard_map``
+    specs and lets GSPMD propagate the rest. ``jax.make_mesh`` defaults to
+    ``Explicit`` axes, under which sharding-in-types refuses, e.g., a
+    ``jnp.take`` from the vocab-sharded embedding table and asks every
+    jit for a ``jax.set_mesh`` context.
+    """
+    axes = tuple(axes)
+    return jax.make_mesh(
+        tuple(shape), axes, axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for subprocess tests (device count forced by XLA_FLAGS)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_train_mesh(data: int = 1, model: int = 1, pipe: int = 1):
@@ -41,7 +58,7 @@ def make_train_mesh(data: int = 1, model: int = 1, pipe: int = 1):
             f"mesh {data}x{model}x{pipe} needs {data * model * pipe} "
             f"devices, have {n}"
         )
-    return jax.make_mesh((data, model, pipe), ("data", "model", "pipe"))
+    return make_mesh((data, model, pipe), ("data", "model", "pipe"))
 
 
 def make_serve_mesh(data: int = 1, model: int = 1):
@@ -55,7 +72,7 @@ def make_serve_mesh(data: int = 1, model: int = 1):
         raise ValueError(
             f"mesh {data}x{model} needs {data * model} devices, have {n}"
         )
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def replica_submeshes(mesh):

@@ -19,10 +19,14 @@
     PYTHONPATH=src python -m repro.launch.serve --arch granite-8b --paged \
         --trace poisson --arrival-rate 0.8 --qos mixed --max-queue 4
 
-With ``--reduced`` (the CPU-container mode) a smoke-size variant of the
-architecture family is instantiated and driven through the real prefill +
-decode path. Without it, the full config is built (requires a TPU fleet;
-params are initialized sharded via the dry-run shardings).
+By default a smoke-size ``reduced()`` variant of the architecture family is
+instantiated (small enough for a CPU) and driven through the real prefill +
+decode path. ``--full`` keeps the published widths and holds the weights in
+bf16, initialized in place on the devices (sharded over ``--mesh``'s model
+axis); ``--layers N`` cuts the depth. On one TPU v5e chip:
+
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-8b --full \
+        --layers 16 --paged --kernel --prefill-chunk 128 --requests 8
 
 ``--paged`` routes through ``repro.serve.ServeEngine``: requests with
 varying prompt lengths are admitted into fixed decode slots against the
@@ -117,8 +121,12 @@ def _replay_cli(args, cfg, eng) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--full", dest="reduced", action="store_false")
+    ap.add_argument("--reduced", action="store_true", default=True,
+                    help="smoke-size reduced() widths (the default)")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="the published widths, weights in bf16")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to N layers (0 = the config's own)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=32)
@@ -199,9 +207,10 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.configs import ASSIGNED, get_config, get_reduced
+    from repro.configs import ASSIGNED, launch_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_serve_mesh
-    from repro.models import Runtime, init_params
+    from repro.models import Runtime, init_params, init_params_as
     from repro.serve import (
         EngineConfig,
         ReplicatedServeEngine,
@@ -211,10 +220,24 @@ def main() -> None:
     from repro.train import generate
 
     assert args.arch in ASSIGNED, f"--arch must be one of {ASSIGNED}"
-    cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
+    enable_compile_cache()
+    cfg = launch_config(args.arch, full=not args.reduced, layers=args.layers)
     mesh = make_serve_mesh(data_par, model_par) if args.mesh else None
     rt = Runtime(dtype=jnp.float32 if args.reduced else jnp.bfloat16, chunk_q=32)
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
+    if args.reduced:
+        params = init_params(cfg, jax.random.PRNGKey(args.seed))
+    else:
+        shardings = None
+        if mesh is not None:
+            from repro.sharding.specs import param_specs, with_sharding
+
+            p_shape = jax.eval_shape(
+                lambda: init_params(cfg, jax.random.PRNGKey(0))
+            )
+            shardings = with_sharding(mesh, param_specs(cfg, p_shape, mesh))
+        params = init_params_as(
+            cfg, jax.random.PRNGKey(args.seed), jnp.bfloat16, shardings
+        )
     rng = np.random.RandomState(args.seed)
 
     if args.paged:

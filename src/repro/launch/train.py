@@ -345,6 +345,19 @@ def build_train_pipeline_host(
     return step, (state_struct, batch_struct), backend
 
 
+def init_train_state(cfg: ArchConfig, tc: TrainConfig, state_struct: Any,
+                     seed: int = 0) -> Dict[str, Any]:
+    """``make_state`` as one jitted program laid out by the shardings of
+    ``state_struct`` (from ``build_train``/``build_train_pipeline``), so a
+    published-width state is built in place on the devices, never on the
+    host or whole on one device."""
+    opt = get_opt(tc.optimizer, tc.lr)
+    shardings = jax.tree.map(lambda s: s.sharding, state_struct)
+    return jax.jit(
+        lambda: make_state(cfg, opt, tc, seed), out_shardings=shardings
+    )()
+
+
 def _params_struct_and_shard(cfg: ArchConfig, mesh, zero3: bool = False):
     from repro.models import init_params
 
@@ -384,7 +397,12 @@ def main() -> None:
     """Real trainer entry point on whatever devices exist.
 
         PYTHONPATH=src python -m repro.launch.train --arch granite-8b \
-            --reduced --steps 20 --batch 16 --seq 128 --zero 2
+            --steps 20 --batch 16 --seq 128 --zero 2
+
+        # published widths, depth cut to one layer, on one TPU chip:
+        PYTHONPATH=src python -m repro.launch.train --arch granite-8b \
+            --full --layers 1 --steps 5 --batch 2 --seq 2048 \
+            --precision bf16 --remat full --fused-backward
 
     2D (default): pjit step via build_train on a (data x model) mesh.
     3D pipelined: ``--pipe P --microbatches M --schedule {gpipe,1f1b}``
@@ -393,24 +411,28 @@ def main() -> None:
     ``core.partitioner.dp_pp_search`` over the real device count (at the
     given ``--tp``) and executes the winning (dp, pp) split. Multi-host
     wiring: set jax.distributed + per-host DataPipeline shard (repro.data).
-    ``--reduced`` instantiates the smoke-size family variant so the driver
-    runs on CPU containers.
+    By default the smoke-size ``reduced()`` variant of the architecture
+    runs, small enough for a CPU; ``--full`` keeps the published widths and
+    ``--layers N`` cuts the depth (``configs.launch_config``).
     """
     import argparse
 
     import numpy as np
 
-    from repro.configs import ASSIGNED, get_reduced
-    import repro.configs.registry as registry
+    from repro.configs import ASSIGNED, launch_config
     from repro.core.partitioner import ParallelPlan, auto_plan
     from repro.data import DataPipeline
-    from repro.launch.mesh import make_train_mesh
-    from repro.optim import get as get_opt
-    from repro.train import make_state
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh, make_train_mesh
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b", choices=ASSIGNED)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true", default=True,
+                    help="smoke-size reduced() widths (the default)")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="the published widths")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to N layers (0 = the config's own)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--seq", type=int, default=128)
@@ -453,10 +475,9 @@ def main() -> None:
                          "stash=host only)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     n = len(jax.devices())
-    cfg = get_reduced(args.arch) if args.reduced else None
-    assert cfg is not None, "--full training requires a TPU fleet"
-    registry.ARCHITECTURES[cfg.name] = cfg
+    cfg = launch_config(args.arch, full=not args.reduced, layers=args.layers)
     shape = ShapeSpec("cli", args.seq, args.batch, "train")
 
     def tp_auto(budget: int) -> int:
@@ -571,14 +592,14 @@ def main() -> None:
                 if n % cand == 0 and cand <= n:
                     model_ax = cand
                     break
-        mesh = jax.make_mesh((n // model_ax, model_ax), ("data", "model"))
+        mesh = make_mesh((n // model_ax, model_ax), ("data", "model"))
         print(f"devices={n} mesh=({n//model_ax} data x {model_ax} model)")
         jitted, (s_struct, b_struct) = build_train(cfg.name, mesh, tc, shape)
 
-    state = make_state(cfg, get_opt(tc.optimizer, tc.lr), tc)
-    state = jax.tree.map(
-        lambda x, st: jax.device_put(x, st.sharding), state, s_struct
-    )
+    if stash_backend is None:
+        state = init_train_state(cfg, tc, s_struct)
+    else:   # the host-driven runner is unjitted: its state stays uncommitted
+        state = make_state(cfg, get_opt(tc.optimizer, tc.lr), tc)
     data = DataPipeline(cfg, args.batch, args.seq, seed=0)
     try:
         import time

@@ -6,6 +6,7 @@ from repro.models.lm import (  # noqa: F401
     init_decode_state,
     init_paged_state,
     init_params,
+    init_params_as,
     loss_fn,
     prefill,
     prefill_chunk_paged,

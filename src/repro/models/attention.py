@@ -246,18 +246,43 @@ def init_paged_kv_cache(
     A quantized ``kv_dtype`` ("int8"/"fp8") stores the pools in the storage
     dtype and adds per-(page-slot, kv-head) f32 scale buffers ``ksc``/``vsc``
     (see ``kernels.paged_attention.quant``); the zero-initialized scales
-    dequantize the null page to exact zeros."""
+    dequantize the null page to exact zeros.
+
+    Layout is head-major, (N, Kv, page, hd) and scales (N, Kv, page): the
+    paged kernels read one kv head's page as a contiguous tile
+    (``kernels/paged_attention/kernel.py``). Writers index
+    ``pool.at[page_id, :, slot]``."""
     from repro.kernels.paged_attention import quant
 
     store = quant.kv_storage_dtype(kv_dtype, dtype)
     pool = {
-        "kp": jnp.zeros((num_pages, page_size, n_kv, head_dim), store),
-        "vp": jnp.zeros((num_pages, page_size, n_kv, head_dim), store),
+        "kp": jnp.zeros((num_pages, n_kv, page_size, head_dim), store),
+        "vp": jnp.zeros((num_pages, n_kv, page_size, head_dim), store),
     }
     if quant.is_quantized(kv_dtype):
-        pool["ksc"] = jnp.zeros((num_pages, page_size, n_kv), jnp.float32)
-        pool["vsc"] = jnp.zeros((num_pages, page_size, n_kv), jnp.float32)
+        pool["ksc"] = jnp.zeros((num_pages, n_kv, page_size), jnp.float32)
+        pool["vsc"] = jnp.zeros((num_pages, n_kv, page_size), jnp.float32)
     return pool
+
+
+def pool_write(pool: jax.Array, pid: jax.Array, slot: jax.Array,
+               rows: jax.Array, layer=None) -> jax.Array:
+    """Write one token row per kv head into a head-major pool leaf.
+
+    pool ([L,] N, Kv, page, ...), written at ``layer`` when stacked;
+    pid/slot (...) page id and in-page slot of each token; rows
+    (..., Kv, ...) its per-head values. The head index is an explicit
+    index of the scatter, so each written window is one trailing row and
+    XLA keeps the pool in its default layout — a ``pool.at[pid, :, slot]``
+    window spanning Kv and hd makes it relayout (copy) the whole pool
+    around every write.
+    """
+    idx = (pid[..., None],
+           jnp.arange(pool.shape[1 if layer is None else 2], dtype=jnp.int32),
+           slot[..., None])
+    if layer is not None:
+        idx = (layer,) + idx
+    return pool.at[idx].set(rows.astype(pool.dtype))
 
 
 def attention_prefill_paged(
@@ -275,10 +300,13 @@ def attention_prefill_paged(
     window: int = 0,
     use_kernel: bool = False,
     mesh=None,
+    layer=None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One prefill *chunk* per slot against the paged KV pool.
 
-    x: (B, T, d) chunk hidden states; cache: {"kp","vp"} (N, page, Kv, hd);
+    x: (B, T, d) chunk hidden states; cache: {"kp","vp"} (N, Kv, page, hd),
+    or all layers' pools stacked (L, N, Kv, page, hd) with ``layer`` the
+    one to write and read (in place: the stack is never sliced);
     tables: (B, P) int32; start: (B,) absolute position of x[:, 0]; q_len:
     (B,) valid rows (rows >= q_len are right-padding: their KV goes to the
     null page and their output is zeroed so downstream per-row compute
@@ -296,7 +324,7 @@ def attention_prefill_paged(
     B, T, _ = x.shape
     dtype = x.dtype
     G = n_heads // n_kv
-    page = cache["kp"].shape[1]
+    page = cache["kp"].shape[-2]
 
     pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (B, T)
     q = rope_apply(_split_heads(x @ p["wq"].astype(dtype), n_heads, head_dim),
@@ -324,20 +352,21 @@ def attention_prefill_paged(
 
         k_codes, k_sc = quant.kv_quantize(k_new, cache["kp"].dtype)
         v_codes, v_sc = quant.kv_quantize(v_new, cache["vp"].dtype)
-        new_cache["kp"] = cache["kp"].at[pid, slot].set(k_codes)
-        new_cache["vp"] = cache["vp"].at[pid, slot].set(v_codes)
-        new_cache["ksc"] = cache["ksc"].at[pid, slot].set(k_sc)
-        new_cache["vsc"] = cache["vsc"].at[pid, slot].set(v_sc)
+        new_cache["kp"] = pool_write(cache["kp"], pid, slot, k_codes, layer)
+        new_cache["vp"] = pool_write(cache["vp"], pid, slot, v_codes, layer)
+        new_cache["ksc"] = pool_write(cache["ksc"], pid, slot, k_sc, layer)
+        new_cache["vsc"] = pool_write(cache["vsc"], pid, slot, v_sc, layer)
         scales = {"k_scale": new_cache["ksc"], "v_scale": new_cache["vsc"]}
     else:
-        new_cache["kp"] = cache["kp"].at[pid, slot].set(k_new)
-        new_cache["vp"] = cache["vp"].at[pid, slot].set(v_new)
+        new_cache["kp"] = pool_write(cache["kp"], pid, slot, k_new, layer)
+        new_cache["vp"] = pool_write(cache["vp"], pid, slot, v_new, layer)
         scales = {}
 
     q = q.reshape(B, T, n_kv, G, head_dim) * (head_dim ** -0.5)
     out = paged_prefill_attention(
         q, new_cache["kp"], new_cache["vp"], tables, start, q_len,
-        window=window, use_kernel=use_kernel, mesh=mesh, **scales,
+        window=window, use_kernel=use_kernel, mesh=mesh, layer=layer,
+        **scales,
     )
     out = jnp.where(valid[:, :, None, None, None], out, 0)
     out = out.astype(dtype).reshape(B, T, n_heads * head_dim)
@@ -359,10 +388,12 @@ def attention_decode_paged(
     window: int = 0,
     use_kernel: bool = False,
     mesh=None,
+    layer=None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decode token per slot against the paged KV pool.
 
-    x: (B, 1, d); cache: {"kp","vp"} (N, page, Kv, hd); tables: (B, P) int32;
+    x: (B, 1, d); cache: {"kp","vp"} ([L,] N, Kv, page, hd) as in
+    ``attention_prefill_paged`` (``layer``); tables: (B, P) int32;
     lengths: (B,) int32 tokens already cached per slot (the new token's
     position); active: (B,) bool — inactive slots write to the null page and
     their output is garbage by contract (the serve engine discards it).
@@ -378,7 +409,7 @@ def attention_decode_paged(
     B = x.shape[0]
     dtype = x.dtype
     G = n_heads // n_kv
-    page = cache["kp"].shape[1]
+    page = cache["kp"].shape[-2]
 
     pos = lengths[:, None].astype(jnp.int32)                   # (B, 1)
     q = rope_apply(_split_heads(x @ p["wq"].astype(dtype), n_heads, head_dim),
@@ -400,20 +431,21 @@ def attention_decode_paged(
 
         k_codes, k_sc = quant.kv_quantize(k_new[:, 0], cache["kp"].dtype)
         v_codes, v_sc = quant.kv_quantize(v_new[:, 0], cache["vp"].dtype)
-        new_cache["kp"] = cache["kp"].at[page_ids, slot].set(k_codes)
-        new_cache["vp"] = cache["vp"].at[page_ids, slot].set(v_codes)
-        new_cache["ksc"] = cache["ksc"].at[page_ids, slot].set(k_sc)
-        new_cache["vsc"] = cache["vsc"].at[page_ids, slot].set(v_sc)
+        new_cache["kp"] = pool_write(cache["kp"], page_ids, slot, k_codes, layer)
+        new_cache["vp"] = pool_write(cache["vp"], page_ids, slot, v_codes, layer)
+        new_cache["ksc"] = pool_write(cache["ksc"], page_ids, slot, k_sc, layer)
+        new_cache["vsc"] = pool_write(cache["vsc"], page_ids, slot, v_sc, layer)
         scales = {"k_scale": new_cache["ksc"], "v_scale": new_cache["vsc"]}
     else:
-        new_cache["kp"] = cache["kp"].at[page_ids, slot].set(k_new[:, 0])
-        new_cache["vp"] = cache["vp"].at[page_ids, slot].set(v_new[:, 0])
+        new_cache["kp"] = pool_write(cache["kp"], page_ids, slot, k_new[:, 0], layer)
+        new_cache["vp"] = pool_write(cache["vp"], page_ids, slot, v_new[:, 0], layer)
         scales = {}
 
     q = q.reshape(B, n_kv, G, head_dim) * (head_dim ** -0.5)
     out = paged_attention(
         q, new_cache["kp"], new_cache["vp"], tables, lengths + 1,
-        window=window, use_kernel=use_kernel, mesh=mesh, **scales,
+        window=window, use_kernel=use_kernel, mesh=mesh, layer=layer,
+        **scales,
     )
     out = out.astype(dtype).reshape(B, 1, n_heads * head_dim)
     return out @ p["wo"].astype(dtype), new_cache

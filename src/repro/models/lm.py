@@ -51,6 +51,19 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> Params:
     return p
 
 
+def init_params_as(
+    cfg: ArchConfig, key: jax.Array, dtype, shardings=None
+) -> Params:
+    """``init_params`` with every leaf cast to ``dtype``, in one jitted
+    program placed by ``shardings`` (a tree matching the params, or None
+    for the default device) — a published-width model is never held in
+    f32 on the device."""
+    return jax.jit(
+        lambda k: jax.tree.map(lambda x: x.astype(dtype), init_params(cfg, k)),
+        out_shardings=shardings,
+    )(key)
+
+
 def _encoder_cfg(cfg: ArchConfig) -> ArchConfig:
     import dataclasses
 

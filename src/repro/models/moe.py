@@ -29,8 +29,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.models.layers import Params, dense_init, subkey
 
 
@@ -78,7 +76,7 @@ def moe_apply(
     if axis_name is None:
         E_total, e0 = E_local, 0
     else:
-        ep = compat.axis_size(axis_name)
+        ep = jax.lax.axis_size(axis_name)
         E_total = E_local * ep
         e0 = jax.lax.axis_index(axis_name) * E_local
 

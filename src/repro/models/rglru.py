@@ -16,9 +16,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.models.layers import Params, dense_init, subkey
 from repro.models.ssm import _causal_conv
@@ -117,7 +115,7 @@ def rglru_apply_seqpar(
 
     def inner(p_, x_):
         dtype = x_.dtype
-        n = compat.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         idx = jax.lax.axis_index(axis)
         u = x_ @ p_["w_in"].astype(dtype)
         gate = jax.nn.gelu(x_ @ p_["w_gate"].astype(dtype))

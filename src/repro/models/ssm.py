@@ -14,9 +14,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.models.layers import Params, dense_init, subkey
 
@@ -162,7 +160,7 @@ def mamba_apply_seqpar(
 
     def inner(p_, x_):
         dtype = x_.dtype
-        n = compat.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         idx = jax.lax.axis_index(axis)
         d_inner = p_["in_proj"].shape[1] // 2
         xz = x_ @ p_["in_proj"].astype(dtype)

@@ -29,8 +29,8 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
@@ -596,13 +596,13 @@ def write_prefill_to_pool(
         if "ksc" in pool:
             k_codes, k_sc = quant.kv_quantize(k[0], pool["kp"].dtype)
             v_codes, v_sc = quant.kv_quantize(v[0], pool["vp"].dtype)
-            new["kp"] = pool["kp"].at[pid, slot].set(k_codes)
-            new["vp"] = pool["vp"].at[pid, slot].set(v_codes)
-            new["ksc"] = pool["ksc"].at[pid, slot].set(k_sc)
-            new["vsc"] = pool["vsc"].at[pid, slot].set(v_sc)
+            new["kp"] = attn_mod.pool_write(pool["kp"], pid, slot, k_codes)
+            new["vp"] = attn_mod.pool_write(pool["vp"], pid, slot, v_codes)
+            new["ksc"] = attn_mod.pool_write(pool["ksc"], pid, slot, k_sc)
+            new["vsc"] = attn_mod.pool_write(pool["vsc"], pid, slot, v_sc)
         else:
-            new["kp"] = pool["kp"].at[pid, slot].set(k[0])
-            new["vp"] = pool["vp"].at[pid, slot].set(v[0])
+            new["kp"] = attn_mod.pool_write(pool["kp"], pid, slot, k[0])
+            new["vp"] = attn_mod.pool_write(pool["vp"], pid, slot, v[0])
         return new
 
     new_pools: List[Any] = []
@@ -613,6 +613,46 @@ def write_prefill_to_pool(
             unit[key] = jax.vmap(scatter)(pool, c["k"], c["v"], c["pos"])
         new_pools.append(unit)
     return new_pools
+
+
+def _paged_scan(cfg, stack, x, caches, rt, specs, mixer):
+    """Run the stack over paged KV pools, ``mixer(p, h, pools, layer,
+    window) -> (out, pools)`` standing in for each attention layer.
+
+    The pools ride the scan CARRY, stacked over the unit repeats, and each
+    layer writes and reads its own slice in place (``layer``): carried as
+    scanned inputs and outputs instead, they would need a second,
+    pool-sized output buffer in every call.
+    """
+    segments = build_segments(cfg, specs)
+    new_caches: List[Any] = []
+
+    for seg, seg_cache in zip(segments, caches):
+        params_seg = segment_params(stack, seg)
+        reps = jax.tree.leaves(seg_cache)[0].shape[0]
+
+        def unit_body(carry, xs, _seg=seg):
+            h, pools = carry
+            unit_p, layer = xs
+            pools = dict(pools)
+            for j, spec in enumerate(_seg.unit_specs):
+                assert spec.kind in ("attn", "local"), spec.kind
+                bp = unit_p[f"p{j}"]
+                hn = norm_apply(bp["norm1"], h, cfg.norm)
+                out, pools[f"p{j}"] = mixer(
+                    bp["mixer"], hn, pools[f"p{j}"], layer, spec.window
+                )
+                h = h + out
+                h, _ = _ffn_apply(cfg, bp, h, rt)
+            return (h, pools), None
+
+        (x, seg_cache), _ = jax.lax.scan(
+            unit_body, (x, seg_cache),
+            (params_seg, jnp.arange(reps, dtype=jnp.int32)),
+        )
+        new_caches.append(seg_cache)
+
+    return x, new_caches
 
 
 def stack_prefill_paged(
@@ -634,34 +674,15 @@ def stack_prefill_paged(
     prefill sibling of ``stack_decode`` — attention-mixer families only
     (the same families the paged engine serves).
     """
-    segments = build_segments(cfg, specs)
-    new_caches: List[Any] = []
+    def mixer(p, h, pools, layer, window):
+        return attn_mod.attention_prefill_paged(
+            p, h, pools, tables, start, q_len,
+            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+            theta=cfg.rope_theta, window=window,
+            use_kernel=rt.use_paged_kernel, mesh=rt.mesh, layer=layer,
+        )
 
-    for seg, seg_cache in zip(segments, caches):
-        params_seg = segment_params(stack, seg)
-
-        def unit_body(h, xs, _seg=seg):
-            unit_p, unit_c = xs
-            new_unit_c = {}
-            for j, spec in enumerate(_seg.unit_specs):
-                assert spec.kind in ("attn", "local"), spec.kind
-                bp = unit_p[f"p{j}"]
-                hn = norm_apply(bp["norm1"], h, cfg.norm)
-                out, new_unit_c[f"p{j}"] = attn_mod.attention_prefill_paged(
-                    bp["mixer"], hn, unit_c[f"p{j}"], tables, start, q_len,
-                    n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                    head_dim=cfg.head_dim, theta=cfg.rope_theta,
-                    window=spec.window, use_kernel=rt.use_paged_kernel,
-                    mesh=rt.mesh,
-                )
-                h = h + out
-                h, _ = _ffn_apply(cfg, bp, h, rt)
-            return h, new_unit_c
-
-        x, new_seg_cache = jax.lax.scan(unit_body, x, (params_seg, seg_cache))
-        new_caches.append(new_seg_cache)
-
-    return x, new_caches
+    return _paged_scan(cfg, stack, x, caches, rt, specs, mixer)
 
 
 def stack_decode(
@@ -684,6 +705,17 @@ def stack_decode(
     the per-slot (B,) lengths vector, and ``tables``/``active`` address the
     pool — each slot decodes at its own depth (continuous batching).
     """
+    if tables is not None:
+        def mixer(p, h, pools, layer, window):
+            return attn_mod.attention_decode_paged(
+                p, h, pools, tables, t, active,
+                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                head_dim=cfg.head_dim, theta=cfg.rope_theta, window=window,
+                use_kernel=rt.use_paged_kernel, mesh=rt.mesh, layer=layer,
+            )
+
+        return _paged_scan(cfg, stack, x, caches, rt, specs, mixer)
+
     segments = build_segments(cfg, specs)
     new_caches: List[Any] = []
 
@@ -698,15 +730,7 @@ def stack_decode(
                 c = unit_c[f"p{j}"]
                 self_c = c["self"] if (cfg.is_encdec and isinstance(c, dict) and "self" in c) else c
                 hn = norm_apply(bp["norm1"], h, cfg.norm)
-                if spec.kind in ("attn", "local") and tables is not None:
-                    out, self_c = attn_mod.attention_decode_paged(
-                        bp["mixer"], hn, self_c, tables, t, active,
-                        n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                        head_dim=cfg.head_dim, theta=cfg.rope_theta,
-                        window=spec.window, use_kernel=rt.use_paged_kernel,
-                        mesh=rt.mesh,
-                    )
-                elif spec.kind in ("attn", "local"):
+                if spec.kind in ("attn", "local"):
                     out, self_c = attn_mod.attention_decode(
                         bp["mixer"], hn, self_c, t,
                         n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,

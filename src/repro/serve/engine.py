@@ -188,6 +188,10 @@ class EngineConfig:
     spec_drafter: str = "ngram"
     # Longest n-gram the prompt-lookup drafter matches on.
     spec_ngram: int = 3
+    # Keep each request's first-token logits (V,) f32 on the host in
+    # ``ServeEngine.first_logits`` — for comparing kernel and oracle
+    # engines; off in serving (a vocab-sized copy per request).
+    keep_first_logits: bool = False
 
     @property
     def chunk_tokens(self) -> int:
@@ -422,6 +426,7 @@ class ServeEngine:
         self._callbacks: Dict[int, Any] = {}   # rid -> on_token(rid, toks, done)
         self._emitted: Dict[int, int] = {}     # tokens DELIVERED per rid
         self._completed_run: set = set()
+        self.first_logits: Dict[int, np.ndarray] = {}
         self._run_t0: Optional[float] = None   # open measurement window
         self.stats: Dict[str, Any] = {
             "ttft_s": {}, "prefill_s": {}, "kv_bytes": {},
@@ -1080,6 +1085,7 @@ class ServeEngine:
         tok0 = self._policy.sample(logits, jax.random.fold_in(rkey, 0))
         tok0.block_until_ready()
         now = time.perf_counter()
+        self._keep_first_logits(req.rid, logits)   # after the TTFT stamp
         # TTFT from SUBMIT time — queue wait included — on every path; a
         # readmitted-after-preemption request whose first token was already
         # delivered keeps its original (honest) TTFT, see _evict
@@ -1441,6 +1447,7 @@ class ServeEngine:
         )
         tok0.block_until_ready()
         now = time.perf_counter()
+        self._keep_first_logits(req.rid, pf_logits)   # after the TTFT stamp
         # TTFT from SUBMIT (queue wait + admission + every chunk), matching
         # the legacy path's origin; prefill compute time kept separately
         self.stats["ttft_s"].setdefault(req.rid, now - req.t_submit)
@@ -1454,6 +1461,31 @@ class ServeEngine:
         slot.phase = "decode"
         self._outputs[req.rid] = [int(tok0[0])]
         self._deliver(req.rid)   # last: a callback may cancel() this slot
+
+    def lowered_programs(self) -> Dict[str, str]:
+        """StableHLO text of the paged engine's decode chunk program (and,
+        on the chunked path, its prefill chunk program), lowered at the
+        engine's current state shapes without running them — to see which
+        kernels a program holds (``tpu_custom_call`` for Pallas)."""
+        d = self._dev
+        out = {"decode": self._chunk_fn.lower(
+            self.params, d["caches"], d["tables"], d["lengths"],
+            d["remaining"], d["tok"], d["keys"], d["steps"],
+        ).as_text()}
+        if self.ecfg.prefix_cache or self.ecfg.prefill_chunk:
+            chunk = (jnp.zeros(self.ecfg.chunk_tokens, jnp.int32),
+                     jnp.int32(0), jnp.int32(0), jnp.int32(1))
+            out["prefill"] = self._prefill_fn.lower(
+                self.params, d["caches"], d["tables"],
+                *(self._place(a) for a in chunk),
+            ).as_text()
+        return out
+
+    def _keep_first_logits(self, rid: int, logits) -> None:
+        if self.ecfg.keep_first_logits:
+            self.first_logits.setdefault(
+                rid, np.asarray(logits, np.float32).reshape(-1)
+            )
 
     def _collect(self, emits: np.ndarray) -> int:
         n = 0

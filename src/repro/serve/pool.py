@@ -1,6 +1,6 @@
 """Host-side KV page-pool allocator for the paged serve engine.
 
-The device side is a set of per-layer ``(num_pages, page_size, Kv, hd)``
+The device side is a set of per-layer ``(num_pages, Kv, page_size, hd)``
 pools plus per-request block tables (``repro.models.stack.init_stack_pool``);
 this module owns the metadata: which pages belong to which sequence, page
 refcounts for prefix sharing, and the free list. It is the inference-side

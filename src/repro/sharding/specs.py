@@ -215,9 +215,9 @@ def _size(mesh, axes) -> int:
 
 
 def pool_kv_spec(cfg: ArchConfig, ndim: int, tp: int) -> P:
-    """Spec for one paged KV pool leaf ``(..., num_pages, page, Kv, hd)``.
+    """Spec for one paged KV pool leaf ``(..., num_pages, Kv, page, hd)``.
 
-    The pool shards over ``model`` on the KV-HEAD axis (dim -2) — the
+    The pool shards over ``model`` on the KV-HEAD axis (dim -3) — the
     Megatron head split applied to serving memory: each chip stores
     ``Kv / tp`` heads of every page, so KV bytes per chip shrink by the TP
     factor while page ids stay globally valid (the block table is
@@ -226,21 +226,21 @@ def pool_kv_spec(cfg: ArchConfig, ndim: int, tp: int) -> P:
     """
     dims: list = [None] * ndim
     if _div(cfg.n_kv_heads, tp) and tp > 1:
-        dims[-2] = "model"
+        dims[-3] = "model"
     return P(*dims)
 
 
 def pool_scale_spec(cfg: ArchConfig, ndim: int, tp: int) -> P:
-    """Spec for a quantized pool's scale leaf ``(..., num_pages, page, Kv)``.
+    """Spec for a quantized pool's scale leaf ``(..., num_pages, Kv, page)``.
 
-    Scales carry no head_dim axis, so the KV-head axis is the LAST dim;
-    it shards over ``model`` under the same divisibility guard as
+    The KV-head axis (dim -2) shards over ``model`` under the same
+    divisibility guard as
     :func:`pool_kv_spec` — each chip stores the scales for exactly the
     head slice of pages it holds.
     """
     dims: list = [None] * ndim
     if _div(cfg.n_kv_heads, tp) and tp > 1:
-        dims[-1] = "model"
+        dims[-2] = "model"
     return P(*dims)
 
 
@@ -249,7 +249,7 @@ def paged_state_specs(cfg: ArchConfig, state_shape: Any, mesh) -> Any:
 
     ``caches`` leaves are page pools (head-sharded, see ``pool_kv_spec``)
     plus, under a quantized ``kv_dtype``, their scale buffers (``ksc`` /
-    ``vsc``, head-sharded on the last dim); ``tables``/``lengths`` (and any
+    ``vsc``, head-sharded on the same kv-head axis); ``tables``/``lengths`` (and any
     other host-updated slot arrays) are replicated — every chip addresses
     the same page ids.
     """

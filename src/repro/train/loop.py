@@ -20,8 +20,8 @@ from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 from repro.core import compression as comp_mod
 from repro.core.precision import (

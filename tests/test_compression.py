@@ -124,10 +124,11 @@ MULTIDEV_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.compression import TopK, QSGD, init_state, sync
 
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     g_global = jnp.asarray(np.random.RandomState(0).randn(8, 64, 64), jnp.float32)
 
     def per_shard(g):   # g: (1, 64, 64) local shard

@@ -31,7 +31,8 @@ SCRIPT = textwrap.dedent(
     ARCH = "{arch}"
     cfg = get_reduced(ARCH)
     registry.ARCHITECTURES[cfg.name] = cfg
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2), ("data", "model"))
     tc = TrainConfig(precision="f32", remat="full", zero_stage={zero})
 
     # ---- train ----

@@ -17,18 +17,15 @@ from repro.kernels.paged_attention import (
     paged_prefill_attention,
     paged_prefill_attention_ref,
 )
-from repro.kernels.paged_attention.kernel import (
-    paged_attention_kernel,
-    paged_prefill_attention_kernel,
-)
+from repro.kernels.paged_attention.kernel import paged_prefill_attention_kernel
 
 
 def make_case(B, Kv, G, hd, page, N, P, lengths, seed=0):
     """Random pool + per-request block tables covering ``lengths`` tokens."""
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(B, Kv, G, hd), jnp.float32) * (hd**-0.5)
-    kp = jnp.asarray(rng.randn(N, page, Kv, hd), jnp.float32)
-    vp = jnp.asarray(rng.randn(N, page, Kv, hd), jnp.float32)
+    kp = jnp.asarray(rng.randn(N, Kv, page, hd), jnp.float32)
+    vp = jnp.asarray(rng.randn(N, Kv, page, hd), jnp.float32)
     # carve disjoint page runs out of 1..N-1 (page 0 = null)
     tables = np.zeros((B, P), np.int32)
     nxt = 1
@@ -64,7 +61,8 @@ def test_kernel_matches_ref(case, window):
 
 def test_kernel_interpret_mode_explicit():
     q, kp, vp, tables, lens = make_case(2, 2, 2, 32, 8, 16, 4, [9, 20], seed=3)
-    out = paged_attention_kernel(q, kp, vp, tables, lens, interpret=True)
+    out = paged_attention(q, kp, vp, tables, lens, use_kernel=True,
+                          interpret=True)
     ref = paged_attention_ref(q, kp, vp, tables, lens)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
@@ -80,8 +78,9 @@ def test_ref_matches_dense_attention():
                                         seed=7)
     out = paged_attention_ref(q, kp, vp, tables, lens)
     for b, L in enumerate(lengths):
-        k = np.asarray(kp)[np.asarray(tables)[b]].reshape(-1, Kv, hd)[:L]
-        v = np.asarray(vp)[np.asarray(tables)[b]].reshape(-1, Kv, hd)[:L]
+        pages = np.asarray(tables)[b]
+        k = np.asarray(kp)[pages].swapaxes(1, 2).reshape(-1, Kv, hd)[:L]
+        v = np.asarray(vp)[pages].swapaxes(1, 2).reshape(-1, Kv, hd)[:L]
         scores = np.einsum("kgh,skh->kgs", np.asarray(q)[b], k)
         w = jax.nn.softmax(jnp.asarray(scores), axis=-1)
         expect = np.einsum("kgs,skh->kgh", np.asarray(w), v)
@@ -191,8 +190,8 @@ def make_prefill_case(B, T, Kv, G, hd, page, N, P, starts, qlens, seed=0):
     """Pool + block tables covering each request's start + T positions."""
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(B, T, Kv, G, hd), jnp.float32) * (hd ** -0.5)
-    kp = jnp.asarray(rng.randn(N, page, Kv, hd), jnp.float32)
-    vp = jnp.asarray(rng.randn(N, page, Kv, hd), jnp.float32)
+    kp = jnp.asarray(rng.randn(N, Kv, page, hd), jnp.float32)
+    vp = jnp.asarray(rng.randn(N, Kv, page, hd), jnp.float32)
     tables = np.zeros((B, P), np.int32)
     nxt = 1
     for b in range(B):
@@ -274,10 +273,10 @@ def test_prefill_causality_ignores_future_garbage():
     kp2, vp2 = np.asarray(kp).copy(), np.asarray(vp).copy()
     for j in range(np.asarray(tbl).shape[1]):
         pid = int(np.asarray(tbl)[0, j])
-        for s in range(kp2.shape[1]):
-            if j * kp2.shape[1] + s > last and pid != 0:
-                kp2[pid, s] = 1e3
-                vp2[pid, s] = -1e3
+        for s in range(kp2.shape[2]):
+            if j * kp2.shape[2] + s > last and pid != 0:
+                kp2[pid, :, s] = 1e3
+                vp2[pid, :, s] = -1e3
     out = paged_prefill_attention(
         q, jnp.asarray(kp2), jnp.asarray(vp2), tbl, st, ln, use_kernel=True
     )

@@ -141,7 +141,8 @@ RUNNER_SCRIPT = textwrap.dedent(
     from repro.core.pipeline import pipeline_apply
 
     P, M, D = 4, 8, 16
-    mesh = jax.make_mesh((4,), ("pipe",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("pipe",))
     rng = np.random.RandomState(0)
     stage_params = {"w": jnp.asarray(rng.randn(P, D, D) * 0.3, jnp.float32),
                     "b": jnp.asarray(rng.randn(P, D) * 0.1, jnp.float32)}

@@ -719,7 +719,8 @@ def test_engine_trivial_mesh_matches_unsharded(arch_state):
     import jax as _jax
 
     cfg, params = arch_state("granite-8b")
-    mesh = _jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     rng = np.random.RandomState(21)
     prompt = rng.randint(0, cfg.vocab_size, (7,)).astype(np.int32)
     ecfg = EngineConfig(max_slots=2, page_size=8, num_pages=17, max_len=32,

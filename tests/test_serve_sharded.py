@@ -158,7 +158,7 @@ QUANT_SCRIPT = HEADER.format(arch="moonshot-v1-16b-a3b") + textwrap.dedent(
     assert b1 == 2 * b2, (b1, b2)             # codes AND scales shard
     leaves = jax.tree.leaves(eng2._dev["caches"])
     assert any(l.dtype == jnp.int8 for l in leaves)      # quantized pool
-    assert any(l.ndim == 4 and l.dtype == jnp.float32    # (R, N, page, Kv)
+    assert any(l.ndim == 4 and l.dtype == jnp.float32    # (R, N, Kv, page)
                for l in leaves)                          # scale buffers
     for eng in (eng1, eng2):
         eng.pool.check()

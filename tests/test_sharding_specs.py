@@ -71,10 +71,11 @@ def test_embed_sharded_head_sharded():
 
 
 def test_pool_kv_spec_shards_head_axis_or_replicates():
-    """Paged KV pools shard their kv-head axis (dim -2) over `model` when
-    the head count divides, and fall back to replication otherwise (MQA)."""
+    """Paged KV pools shard their kv-head axis (dim -3 of the head-major
+    (..., N, Kv, page, hd) pool) over `model` when the head count divides,
+    and fall back to replication otherwise (MQA)."""
     gqa = get_config("moonshot-v1-16b-a3b")      # 16 kv heads
-    assert pool_kv_spec(gqa, 5, 2) == P(None, None, None, "model", None)
+    assert pool_kv_spec(gqa, 5, 2) == P(None, None, "model", None, None)
     full = get_config("granite-8b")              # 8 kv heads at full size...
     red = get_reduced("granite-8b")              # ...but 1 when reduced (MQA)
     assert red.n_kv_heads == 1
@@ -90,14 +91,14 @@ def test_paged_state_specs_tables_replicated():
     cfg = get_reduced("moonshot-v1-16b-a3b")
     state_shape = {
         "caches": [{"p0": {
-            "kp": jnp.zeros((2, 9, 8, cfg.n_kv_heads, cfg.head_dim)),
-            "vp": jnp.zeros((2, 9, 8, cfg.n_kv_heads, cfg.head_dim)),
+            "kp": jnp.zeros((2, 9, cfg.n_kv_heads, 8, cfg.head_dim)),
+            "vp": jnp.zeros((2, 9, cfg.n_kv_heads, 8, cfg.head_dim)),
         }}],
         "tables": jnp.zeros((4, 8), jnp.int32),
         "lengths": jnp.zeros((4,), jnp.int32),
     }
     specs = paged_state_specs(cfg, state_shape, FakeMesh({"data": 1, "model": 2}))
-    assert specs["caches"][0]["p0"]["kp"] == P(None, None, None, "model", None)
+    assert specs["caches"][0]["p0"]["kp"] == P(None, None, "model", None, None)
     assert specs["tables"] == P(None, None)
     assert specs["lengths"] == P(None)
 

@@ -19,7 +19,8 @@ SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from repro.models.ssm import init_mamba, mamba_apply, mamba_apply_seqpar
 
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 4), ("data", "model"))
     d, di, s, K = 32, 64, 8, 4
     p = init_mamba(jax.random.PRNGKey(0), d, di, s, K)
     x = jnp.asarray(np.random.RandomState(1).randn(2, 64, d), jnp.float32)

@@ -162,7 +162,8 @@ def test_checkpoint_reshard_on_load():
                 np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
 
             # and into the plain 2D pjit trainer
-            mesh_c = jax.make_mesh((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh_c = make_mesh((2, 2), ("data", "model"))
             jit_c, (sc_struct, bc_struct) = build_train(
                 TINY.name, mesh_c, tc, shape)
             state_c = restore_resharded(d, sc_struct)
