@@ -78,6 +78,7 @@ def quantize_pallas(x: jax.Array, block: int = BLOCK, interpret=None):
             jax.ShapeDtypeStruct((rows, block), jnp.uint8),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
+        name="quantize_pallas",
         interpret=interpret,
     )(xb, codes)
     return out.reshape(-1), scale[:, 0]
@@ -101,6 +102,7 @@ def dequantize_pallas(
         ],
         out_specs=pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, block), jnp.float32),
+        name="dequantize_pallas",
         interpret=interpret,
     )(idx.reshape(rows, block), scale[:, None], codes)
     return out.reshape(-1)
@@ -172,6 +174,7 @@ def stash_quantize_pallas(
             jax.ShapeDtypeStruct((prows, block), sdt),
             jax.ShapeDtypeStruct((prows, 1), jnp.float32),
         ],
+        name="stash_quantize_pallas",
         interpret=interpret,
     )(xp)
     return codes[:rows], scale[:rows, 0]
@@ -202,6 +205,7 @@ def stash_dequantize_pallas(
         ],
         out_specs=pl.BlockSpec((STASH_TILE_ROWS, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((prows, block), jnp.dtype(dtype)),
+        name="stash_dequantize_pallas",
         interpret=interpret,
     )(cp, sp)
     return out[:rows]

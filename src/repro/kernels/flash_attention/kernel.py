@@ -169,6 +169,7 @@ def flash_attention_fwd_flat(
             _vmem((block_q,), jnp.float32),
             _vmem((block_q, hd), jnp.float32),
         ],
+        name="flash_attention_fwd_flat",
         interpret=interpret,
     )(q, k, v)
     return o[:, :S], lse[:, :, :S]

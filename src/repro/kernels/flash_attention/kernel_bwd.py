@@ -178,6 +178,7 @@ def flash_attention_bwd_flat(
         ],
         out_specs=pl.BlockSpec((1, 1, block_q), lambda h, i: (h, 0, i)),
         out_shape=jax.ShapeDtypeStruct((BH, 1, Sp), jnp.float32),
+        name="flash_attention_bwd_flat_delta",
         interpret=interpret,
     )(o, do)
 
@@ -199,6 +200,7 @@ def flash_attention_bwd_flat(
         out_specs=pl.BlockSpec((1, block_q, hd), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sp, hd), q.dtype),
         scratch_shapes=[_vmem((block_q, hd), jnp.float32)],
+        name="flash_attention_bwd_flat_dq",
         interpret=interpret,
     )(q, k, v, do, lse_p, delta)
 
@@ -248,6 +250,7 @@ def flash_attention_bwd_flat(
             _vmem((block_k, hd), jnp.float32),
             _vmem((block_k, hd), jnp.float32),
         ],
+        name="flash_attention_bwd_flat_dkv",
         interpret=interpret,
     )(q, do, lse_p, delta, k, v)
 

@@ -196,6 +196,7 @@ def paged_prefill_attention_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, T * G, hd), q.dtype),
+        name="paged_prefill_attention_kernel",
         interpret=interpret,
     )(
         jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),

@@ -88,6 +88,7 @@ def rmsnorm_pallas(
         ],
         out_specs=pl.BlockSpec((tile, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(((rows + pad), d), x.dtype),
+        name="rmsnorm_pallas",
         interpret=interpret,
     )(xr, scale[None, :])
     return out[:rows].reshape(orig_shape)
@@ -126,6 +127,7 @@ def rmsnorm_bwd_pallas(
             jax.ShapeDtypeStruct(((rows + pad), d), x.dtype),
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
+        name="rmsnorm_bwd_pallas",
         interpret=interpret,
     )(xr, scale[None, :], gr)
     return dx[:rows].reshape(orig_shape), dscale[0]

@@ -199,7 +199,7 @@ def make_pipeline_step(cfg: ArchConfig, mesh, plan, tc: TrainConfig, opt):
 
     from repro.core.pipeline import pipeline_grads
 
-    def step(state, batch):
+    def pipeline_train_step(state, batch):
         params = state["params"]
         stack = params["stack"]
         shared = {k: v for k, v in params.items() if k != "stack"}
@@ -237,7 +237,7 @@ def make_pipeline_step(cfg: ArchConfig, mesh, plan, tc: TrainConfig, opt):
         metrics = {"loss": loss, "xent": xent, "z_loss": zl, "aux": aux}
         return finish_step(state, grads, metrics, tc, policy, opt)
 
-    return step
+    return pipeline_train_step
 
 
 def build_train_pipeline(

@@ -3,6 +3,12 @@
 All modules are pure functions: ``init_*`` returns a params pytree (f32
 masters), ``*_apply`` consumes params + activations. Compute dtype is passed
 explicitly (mixed-precision policy lives in ``repro.core.precision``).
+
+The regions of the train step run under fixed ``jax.named_scope`` names:
+``embed`` and ``norm`` here, ``attn`` and ``mlp`` in ``models.stack``,
+``lm_head`` in ``models.lm``, ``optimizer`` in ``train.loop``. They are op
+metadata only, and the chip benchmark reads device time per region by them
+(``benchmarks/chip/chipbench/scopes.py``).
 """
 from __future__ import annotations
 
@@ -37,6 +43,7 @@ def init_norm(norm: str, d: int) -> Params:
     return p
 
 
+@jax.named_scope("norm")
 def norm_apply(
     p: Params, x: jax.Array, norm: str, eps: float = 1e-6, fused: bool = False
 ) -> jax.Array:
@@ -109,6 +116,7 @@ def init_embed(key: jax.Array, vocab: int, d: int) -> Params:
     return {"table": 0.02 * jax.random.normal(subkey(key, "embed"), (vocab, d), jnp.float32)}
 
 
+@jax.named_scope("embed")
 def embed_apply(p: Params, ids: jax.Array, dtype: jnp.dtype) -> jax.Array:
     return jnp.take(p["table"].astype(dtype), ids, axis=0)
 

@@ -179,23 +179,24 @@ def loss_fn(
         n_prefix = batch["frontend_embeds"].shape[1]
         h = h[:, n_prefix:]
     safe = jnp.maximum(labels, 0)
-    if rt.fused_backward:
-        from repro.kernels.chunked_ce import chunked_ce
+    with jax.named_scope("lm_head"):
+        if rt.fused_backward:
+            from repro.kernels.chunked_ce import chunked_ce
 
-        w = (
-            params["embed"]["table"]
-            if cfg.tie_embeddings
-            else params["head"]["w"].T
-        )
-        lab, logz = chunked_ce(h, w, safe, rt.ce_chunk)
-        ll = lab - logz
-    else:
-        logits = logits_apply(
-            params.get("head"), params["embed"], h, cfg.tie_embeddings
-        )
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        ll = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0] - logz
-    xent, zl = masked_token_ce(ll, logz, labels, z_loss)
+            w = (
+                params["embed"]["table"]
+                if cfg.tie_embeddings
+                else params["head"]["w"].T
+            )
+            lab, logz = chunked_ce(h, w, safe, rt.ce_chunk)
+            ll = lab - logz
+        else:
+            logits = logits_apply(
+                params.get("head"), params["embed"], h, cfg.tie_embeddings
+            )
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            ll = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0] - logz
+        xent, zl = masked_token_ce(ll, logz, labels, z_loss)
     total = xent + zl + cfg.router_aux_coef * aux
     metrics = {"loss": total, "xent": xent, "aux": aux, "z_loss": zl}
     return total, metrics
@@ -245,15 +246,16 @@ def pipeline_fns(cfg: ArchConfig, rt: Runtime, tp: int = 1):
 
     def last_fn(shared: Params, y: jax.Array, mb: Dict[str, jax.Array]):
         h = norm_apply(shared["final_norm"], y, cfg.norm)
-        logits = logits_apply(
-            shared.get("head"), shared["embed"], h, cfg.tie_embeddings
-        )
-        labels = mb["labels"]
-        safe = jnp.maximum(labels, 0)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        ll = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0] - logz
-        xent, zl = masked_token_ce(ll, logz, labels)
-        return xent + zl, {"xent": xent, "z_loss": zl}
+        with jax.named_scope("lm_head"):
+            logits = logits_apply(
+                shared.get("head"), shared["embed"], h, cfg.tie_embeddings
+            )
+            labels = mb["labels"]
+            safe = jnp.maximum(labels, 0)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            ll = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0] - logz
+            xent, zl = masked_token_ce(ll, logz, labels)
+            return xent + zl, {"xent": xent, "z_loss": zl}
 
     return first_fn, stage_fn, last_fn
 

@@ -23,6 +23,7 @@ Megatron tensor parallelism (``tp_region_start/end``); see the
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
@@ -279,6 +280,7 @@ def _seq_shard_constraint(h: jax.Array, rt: Runtime) -> jax.Array:
     )
 
 
+@jax.named_scope("mlp")
 def _ffn_apply(
     cfg: ArchConfig, p: Params, x: jax.Array, rt: Runtime
 ) -> Tuple[jax.Array, jax.Array]:
@@ -331,6 +333,15 @@ def _moe_dispatch(
     return fn(p, h)
 
 
+def _mixer_scope(kind: str):
+    """The named scope of an attention layer's ops (QKV and output
+    projections, RoPE, the kernel): ``attn``, the device trace's region.
+    Recurrent mixers run under no scope."""
+    if kind in ("attn", "local"):
+        return jax.named_scope("attn")
+    return contextlib.nullcontext()
+
+
 def _mixer_apply(
     cfg: ArchConfig,
     p: Params,
@@ -342,48 +353,50 @@ def _mixer_apply(
     causal: bool = True,
     cache_len: Optional[int] = None,
 ):
-    h = norm_apply(p["norm1"], x, cfg.norm, fused=rt.fused_backward)
-    if spec.kind in ("attn", "local"):
-        out, kv = attn_mod.attention_apply(
-            p["mixer"], h,
-            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
-            theta=cfg.rope_theta, window=spec.window, causal=causal,
-            positions=positions, chunk_q=rt.chunk_q, collect_kv=collect,
-            use_kernel=rt.use_flash_kernel,
-        )
-        cache = None
+    with _mixer_scope(spec.kind):
+        h = norm_apply(p["norm1"], x, cfg.norm, fused=rt.fused_backward)
+        if spec.kind in ("attn", "local"):
+            out, kv = attn_mod.attention_apply(
+                p["mixer"], h,
+                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+                theta=cfg.rope_theta, window=spec.window, causal=causal,
+                positions=positions, chunk_q=rt.chunk_q, collect_kv=collect,
+                use_kernel=rt.use_flash_kernel,
+            )
+            cache = None
+            if collect:
+                cache = attn_mod.fill_kv_cache(
+                    attn_mod.init_kv_cache(
+                        x.shape[0], cache_len or spec.cache_len, cfg.n_kv_heads,
+                        cfg.head_dim, rt.dtype,
+                    ),
+                    kv["k"], kv["v"], positions,
+                )
+            return x + out, cache
+        if spec.kind == "mamba":
+            if rt.ssm_seqpar and rt.mesh is not None and not collect:
+                res = ssm_mod.mamba_apply_seqpar(
+                    p["mixer"], h, mesh=rt.mesh, batch_axes=rt.batch_axes,
+                )
+            else:
+                res = ssm_mod.mamba_apply(
+                    p["mixer"], h, scan_mode=rt.scan_mode, chunk=rt.ssm_chunk,
+                    collect_state=collect,
+                )
+        else:
+            if rt.ssm_seqpar and rt.mesh is not None and not collect:
+                res = rglru_mod.rglru_apply_seqpar(
+                    p["mixer"], h, mesh=rt.mesh, batch_axes=rt.batch_axes,
+                )
+            else:
+                res = rglru_mod.rglru_apply(p["mixer"], h, collect_state=collect)
         if collect:
-            cache = attn_mod.fill_kv_cache(
-                attn_mod.init_kv_cache(
-                    x.shape[0], cache_len or spec.cache_len, cfg.n_kv_heads,
-                    cfg.head_dim, rt.dtype,
-                ),
-                kv["k"], kv["v"], positions,
-            )
-        return x + out, cache
-    if spec.kind == "mamba":
-        if rt.ssm_seqpar and rt.mesh is not None and not collect:
-            res = ssm_mod.mamba_apply_seqpar(
-                p["mixer"], h, mesh=rt.mesh, batch_axes=rt.batch_axes,
-            )
-        else:
-            res = ssm_mod.mamba_apply(
-                p["mixer"], h, scan_mode=rt.scan_mode, chunk=rt.ssm_chunk,
-                collect_state=collect,
-            )
-    else:
-        if rt.ssm_seqpar and rt.mesh is not None and not collect:
-            res = rglru_mod.rglru_apply_seqpar(
-                p["mixer"], h, mesh=rt.mesh, batch_axes=rt.batch_axes,
-            )
-        else:
-            res = rglru_mod.rglru_apply(p["mixer"], h, collect_state=collect)
-    if collect:
-        out, cache = res
-        return x + out, cache
-    return x + res, None
+            out, cache = res
+            return x + out, cache
+        return x + res, None
 
 
+@jax.named_scope("attn")
 def _cross_apply(
     cfg: ArchConfig, p: Params, x: jax.Array, memory: jax.Array, rt: Runtime
 ) -> jax.Array:
@@ -890,44 +903,46 @@ def stack_stage_apply(
     positions = jnp.tile(jnp.arange(S, dtype=jnp.int32)[None, :], (B, 1))
 
     def block(h, p):
-        hn = norm_apply(p["norm1"], h, cfg.norm)
-        if spec.kind in ("attn", "local"):
-            if tp > 1:
-                hn = tp_region_start(hn, tp_axis)
-            out, _ = attn_mod.attention_apply(
-                p["mixer"], hn,
-                n_heads=cfg.n_heads // tp, n_kv=cfg.n_kv_heads // tp,
-                head_dim=cfg.head_dim, theta=cfg.rope_theta,
-                window=spec.window, positions=positions, chunk_q=rt.chunk_q,
-            )
-            if tp > 1:
-                out = tp_region_end(out, tp_axis)
-        elif spec.kind == "mamba":
-            assert tp == 1, "mamba stages run at tp=1 (see pipeline_incompatibility)"
-            out = ssm_mod.mamba_apply(
-                p["mixer"], hn, scan_mode=rt.scan_mode, chunk=rt.ssm_chunk
-            )
-        else:
-            assert tp == 1, "rglru stages run at tp=1"
-            out = rglru_mod.rglru_apply(p["mixer"], hn)
-        h = h + out
+        with _mixer_scope(spec.kind):
+            hn = norm_apply(p["norm1"], h, cfg.norm)
+            if spec.kind in ("attn", "local"):
+                if tp > 1:
+                    hn = tp_region_start(hn, tp_axis)
+                out, _ = attn_mod.attention_apply(
+                    p["mixer"], hn,
+                    n_heads=cfg.n_heads // tp, n_kv=cfg.n_kv_heads // tp,
+                    head_dim=cfg.head_dim, theta=cfg.rope_theta,
+                    window=spec.window, positions=positions, chunk_q=rt.chunk_q,
+                )
+                if tp > 1:
+                    out = tp_region_end(out, tp_axis)
+            elif spec.kind == "mamba":
+                assert tp == 1, "mamba stages run at tp=1 (see pipeline_incompatibility)"
+                out = ssm_mod.mamba_apply(
+                    p["mixer"], hn, scan_mode=rt.scan_mode, chunk=rt.ssm_chunk
+                )
+            else:
+                assert tp == 1, "rglru stages run at tp=1"
+                out = rglru_mod.rglru_apply(p["mixer"], hn)
+            h = h + out
         aux = jnp.zeros((), jnp.float32)
         if cfg.ffn_kind != "none":
-            h2 = norm_apply(p["norm2"], h, cfg.norm)
-            if cfg.ffn_kind == "dense":
-                if tp > 1:
-                    h2 = tp_region_start(h2, tp_axis)
-                o = mlp_apply(p["ffn"], h2, cfg.mlp_gated)
-                if tp > 1:
-                    o = tp_region_end(o, tp_axis)
-            else:
-                o, aux = moe_mod.moe_apply(
-                    p["ffn"], h2, top_k=cfg.experts_top_k,
-                    capacity_factor=cfg.capacity_factor, gated=cfg.mlp_gated,
-                )
-                if "extra_mlp" in p:
-                    o = o + mlp_apply(p["extra_mlp"], h2, cfg.mlp_gated)
-            h = h + o
+            with jax.named_scope("mlp"):
+                h2 = norm_apply(p["norm2"], h, cfg.norm)
+                if cfg.ffn_kind == "dense":
+                    if tp > 1:
+                        h2 = tp_region_start(h2, tp_axis)
+                    o = mlp_apply(p["ffn"], h2, cfg.mlp_gated)
+                    if tp > 1:
+                        o = tp_region_end(o, tp_axis)
+                else:
+                    o, aux = moe_mod.moe_apply(
+                        p["ffn"], h2, top_k=cfg.experts_top_k,
+                        capacity_factor=cfg.capacity_factor, gated=cfg.mlp_gated,
+                    )
+                    if "extra_mlp" in p:
+                        o = o + mlp_apply(p["extra_mlp"], h2, cfg.mlp_gated)
+                h = h + o
         return h, aux
 
     pol = policy_for(rt.remat)

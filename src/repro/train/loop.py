@@ -87,6 +87,7 @@ def _runtime(cfg: ArchConfig, tc: TrainConfig) -> Runtime:
                    fused_stash=tc.fused_stash)
 
 
+@jax.named_scope("optimizer")
 def finish_step(
     state: Dict[str, Any],
     grads: Any,
@@ -165,14 +166,17 @@ def make_train_step(
         )
         return finish_step(state, grads, metrics, tc, policy, opt, axis_name)
 
+    def train_step(state, batch):
+        return core_step(state, batch, None)
+
     if mode == "single":
-        return jax.jit(lambda state, batch: core_step(state, batch, None))
+        return jax.jit(train_step)
 
     if mode == "core":
         # unjitted step on GLOBAL arrays — the distributed trainer jits it
         # with explicit in/out shardings (pjit handles the data-parallel mean
         # through the global-batch loss; no axis_name needed)
-        return lambda state, batch: core_step(state, batch, None)
+        return train_step
 
     if mode == "dp_compressed":
         assert mesh is not None
