@@ -14,8 +14,17 @@ GQA: the grid's head axis enumerates query heads; the k/v index_map divides
 by the group size so each kv head's tiles are shared by its G query heads.
 
 Sequence lengths need not be block multiples: inputs are zero-padded up to
-the tile grid and real extents are masked via the static ``q_len``/``kv_len``
-kernel parameters.
+the tile grid and real extents are masked via ``TileMask``'s static
+``q_len``/``kv_len``.
+
+Tiles the mask reaches differently do different work (``TileMask``): a dead
+tile (no valid pair: above the causal diagonal, left of a window band) runs
+no body, and the k/v index_map clamps its block index into the row's live
+range, so consecutive steps repeat a block index and the pipeline fetches
+nothing for the dead ones; a full tile (every pair valid) skips the
+iota/compare/where; only partial tiles (the diagonal, window edges, kv
+padding) build the mask. A skipped tile would have added exact zeros, so
+the result is the same.
 
 Backward: fully kernel-fused (``kernel_bwd.py``) — the forward additionally
 emits the per-row logsumexp ``lse = m + log(l)`` so the backward can
@@ -25,6 +34,7 @@ stats instead of replaying the softmax reduction. ``repro.kernels
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Tuple
 
@@ -66,10 +76,93 @@ def make_mask(
     return mask
 
 
+@dataclasses.dataclass(frozen=True)
+class TileMask:
+    """Which pairs of the (q block, k block) tile grid ``make_mask`` reaches.
+
+    Works on Python ints and on traced grid indices alike. A tile is dead
+    (``live`` false) when ``make_mask`` is false over all of it, full when
+    it is true over all of it, and partial otherwise; padded q rows count
+    as ``make_mask`` counts them, with no bound on ``qpos``.
+    """
+
+    causal: bool
+    window: int
+    q_len: int
+    kv_len: int
+    block_q: int
+    block_k: int
+
+    @property
+    def n_q(self) -> int:
+        return -(-self.q_len // self.block_q)
+
+    @property
+    def n_k(self) -> int:
+        return -(-self.kv_len // self.block_k)
+
+    def classify(self, i, j):
+        """(live, full) of tile (q block i, k block j)."""
+        q0, k0 = i * self.block_q, j * self.block_k
+        q1, k1 = q0 + self.block_q - 1, k0 + self.block_k - 1
+        live = k0 < self.kv_len
+        full = k1 < self.kv_len
+        if self.causal:
+            live = jnp.logical_and(live, k0 <= q1)
+            full = jnp.logical_and(full, k1 <= q0)
+        if self.window > 0:
+            k_last = jnp.minimum(k1, self.kv_len - 1)
+            live = jnp.logical_and(live, q0 - k_last < self.window)
+            full = jnp.logical_and(full, q1 - k0 < self.window)
+        return live, full
+
+    def k_blocks(self, i):
+        """(lo, hi): every live tile of q block i has lo <= j <= hi."""
+        q0 = i * self.block_q
+        lo, hi = 0, self.n_k - 1
+        if self.causal:
+            hi = jnp.minimum(hi, (q0 + self.block_q - 1) // self.block_k)
+        if self.window > 0:
+            lo = jnp.maximum(q0 - self.window + 1, 0) // self.block_k
+        return lo, hi
+
+    def q_blocks(self, j):
+        """(lo, hi): every live tile of k block j has lo <= i <= hi."""
+        k0 = j * self.block_k
+        lo, hi = 0, self.n_q - 1
+        if self.causal:
+            lo = k0 // self.block_q
+        if self.window > 0:
+            k_last = jnp.minimum(k0 + self.block_k - 1, self.kv_len - 1)
+            hi = jnp.minimum(hi, (self.window + k_last - 1) // self.block_q)
+        return lo, hi
+
+    def mask(self, i, j) -> jax.Array:
+        """The (block_q, block_k) ``make_mask`` of tile (i, j)."""
+        shape = (self.block_q, self.block_k)
+        qpos = i * self.block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        kpos = j * self.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return make_mask(qpos, kpos, causal=self.causal, window=self.window,
+                         kv_len=self.kv_len)
+
+
+def run_by_class(tiles: TileMask, i, j, body) -> None:
+    """Run ``body(mask)`` on a live tile: mask None where the tile is full,
+    the tile's mask where it is partial; nothing where it is dead."""
+    live, full = tiles.classify(i, j)
+
+    @pl.when(full)
+    def _full():
+        body(None)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
+    def _partial():
+        body(tiles.mask(i, j))
+
+
 def _fa_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, causal: bool, window: int, block_q: int, block_k: int, n_k: int,
-    kv_len: int,
+    *, tiles: TileMask,
 ):
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -80,33 +173,34 @@ def _fa_fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)              # (bq, hd)
-    k = k_ref[0].astype(jnp.float32)              # (bk, hd)
-    v = v_ref[0].astype(jnp.float32)
+    def body(mask):
+        q = q_ref[0].astype(jnp.float32)          # (bq, hd)
+        k = k_ref[0].astype(jnp.float32)          # (bk, hd)
+        v = v_ref[0].astype(jnp.float32)
 
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                              # (bq, bk)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )                                          # (bq, bk)
+        if mask is not None:
+            scores = jnp.where(mask, scores, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1))
+        p = jnp.exp(scores - m_new[:, None])
+        if mask is not None:
+            # explicit mask multiply: exp() of fully-masked rows contributes 0
+            p = jnp.where(mask, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_scr[...] * alpha + jnp.sum(p, axis=1)
+        acc_new = acc_scr[...] * alpha[:, None] + jax.lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        m_scr[...] = m_new
+        l_scr[...] = l_new
+        acc_scr[...] = acc_new
 
-    qpos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    mask = make_mask(qpos, kpos, causal=causal, window=window, kv_len=kv_len)
+    run_by_class(tiles, i, j, body)
 
-    scores = jnp.where(mask, scores, NEG_INF)
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1))
-    # explicit mask multiply: exp() of fully-masked tiles contributes 0
-    p = jnp.where(mask, jnp.exp(scores - m_new[:, None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_scr[...] * alpha + jnp.sum(p, axis=1)
-    acc_new = acc_scr[...] * alpha[:, None] + jax.lax.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-    acc_scr[...] = acc_new
-
-    @pl.when(j == n_k - 1)
+    @pl.when(j == tiles.n_k - 1)
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
@@ -141,20 +235,20 @@ def flash_attention_fwd_flat(
     q = pad_seq(q, block_q)
     k = pad_seq(k, block_k)
     v = pad_seq(v, block_k)
-    Sp, Skp = q.shape[1], k.shape[1]
-    n_q, n_k = Sp // block_q, Skp // block_k
+    Sp = q.shape[1]
+    tiles = TileMask(causal, window, S, Sk, block_q, block_k)
 
-    kernel = functools.partial(
-        _fa_fwd_kernel, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, n_k=n_k, kv_len=Sk,
-    )
+    def kv_map(h, i, j):
+        # dead steps repeat the nearest live block: no fetch for them
+        return h // group, jnp.clip(j, *tiles.k_blocks(i)), 0
+
     o, lse = pl.pallas_call(
-        kernel,
-        grid=(BH, n_q, n_k),
+        functools.partial(_fa_fwd_kernel, tiles=tiles),
+        grid=(BH, tiles.n_q, tiles.n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda h, i, j, g=group: (h // g, j, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda h, i, j, g=group: (h // g, j, 0)),
+            pl.BlockSpec((1, block_k, hd), kv_map),
+            pl.BlockSpec((1, block_k, hd), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, hd), lambda h, i, j: (h, i, 0)),

@@ -20,7 +20,10 @@ Scores are recomputed from q/k and the saved forward stats ``lse = m +
 log(l)``; nothing quadratic in sequence length is ever read from or written
 to HBM. Masking (causal / sliding window / kv padding) matches the forward:
 probabilities use an explicit mask-where so fully-masked rows (reachable via
-sliding windows and block padding) contribute exact zeros, never NaNs.
+sliding windows and block padding) contribute exact zeros, never NaNs. As in
+the forward, dead tiles run no body and fetch no new block (the index_maps
+clamp into the live range: k blocks of a q block in pass 1, q blocks of a k
+block in pass 2), and full tiles skip the mask.
 """
 from __future__ import annotations
 
@@ -32,10 +35,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.flash_attention.kernel import (
+    TileMask,
     _vmem,
-    make_mask,
     pad_seq,
     pick_blocks,
+    run_by_class,
 )
 from repro.kernels.runtime import resolve_interpret
 
@@ -48,8 +52,7 @@ def _fa_bwd_delta_kernel(o_ref, do_ref, delta_ref):
 
 def _fa_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-    *, causal: bool, window: int, block_q: int, block_k: int, n_k: int,
-    kv_len: int,
+    *, tiles: TileMask,
 ):
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -58,28 +61,29 @@ def _fa_bwd_dq_kernel(
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    q = q_ref[0].astype(jnp.float32)              # (bq, hd)
-    k = k_ref[0].astype(jnp.float32)              # (bk, hd)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)            # (bq, hd)
-    lse = lse_ref[0, 0]                           # (bq,)
-    delta = delta_ref[0, 0]                       # (bq,)
+    def body(mask):
+        q = q_ref[0].astype(jnp.float32)          # (bq, hd)
+        k = k_ref[0].astype(jnp.float32)          # (bk, hd)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)        # (bq, hd)
+        lse = lse_ref[0, 0]                       # (bq,)
+        delta = delta_ref[0, 0]                   # (bq,)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                              # (bq, bk)
-    qpos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    mask = make_mask(qpos, kpos, causal=causal, window=window, kv_len=kv_len)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )                                          # (bq, bk)
+        p = jnp.exp(s - lse[:, None])
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )                                          # (bq, bk)
+        ds = p * (dp - delta[:, None])
+        dq_scr[...] += jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
 
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                              # (bq, bk)
-    ds = p * (dp - delta[:, None])
-    dq_scr[...] += jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+    run_by_class(tiles, i, j, body)
 
-    @pl.when(j == n_k - 1)
+    @pl.when(j == tiles.n_k - 1)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -87,45 +91,45 @@ def _fa_bwd_dq_kernel(
 def _fa_bwd_dkv_kernel(
     q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref, dv_ref,
     dk_scr, dv_scr,
-    *, causal: bool, window: int, block_q: int, block_k: int, n_q: int,
-    n_inner: int, kv_len: int,
+    *, tiles: TileMask, n_inner: int,
 ):
     jk = pl.program_id(1)
     t = pl.program_id(2)              # enumerates (group member g, q block qi)
-    qi = t % n_q
+    qi = t % tiles.n_q
 
     @pl.when(t == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q = q_ref[0].astype(jnp.float32)              # (bq, hd)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                           # (bq,)
-    delta = delta_ref[0, 0]
-    k = k_ref[0].astype(jnp.float32)              # (bk, hd)
-    v = v_ref[0].astype(jnp.float32)
+    def body(mask):
+        q = q_ref[0].astype(jnp.float32)          # (bq, hd)
+        do = do_ref[0].astype(jnp.float32)
+        lse = lse_ref[0, 0]                       # (bq,)
+        delta = delta_ref[0, 0]
+        k = k_ref[0].astype(jnp.float32)          # (bk, hd)
+        v = v_ref[0].astype(jnp.float32)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                              # (bq, bk)
-    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    kpos = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    mask = make_mask(qpos, kpos, causal=causal, window=window, kv_len=kv_len)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )                                          # (bq, bk)
+        p = jnp.exp(s - lse[:, None])
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        # dv += p^T dO
+        dv_scr[...] += jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        ds = p * (dp - delta[:, None])
+        # dk += ds^T q
+        dk_scr[...] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
 
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-    # dv += p^T dO
-    dv_scr[...] += jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ds = p * (dp - delta[:, None])
-    # dk += ds^T q
-    dk_scr[...] += jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    run_by_class(tiles, qi, jk, body)
 
     @pl.when(t == n_inner - 1)
     def _finish():
@@ -166,7 +170,8 @@ def flash_attention_bwd_flat(
     k = pad_seq(k, block_k)
     v = pad_seq(v, block_k)
     Sp, Skp = q.shape[1], k.shape[1]
-    n_q, n_k = Sp // block_q, Skp // block_k
+    tiles = TileMask(causal, window, S, Sk, block_q, block_k)
+    n_q, n_k = tiles.n_q, tiles.n_k
 
     # pass 0: per-row delta = sum(dO * O)
     delta = pl.pallas_call(
@@ -182,17 +187,18 @@ def flash_attention_bwd_flat(
         interpret=interpret,
     )(o, do)
 
-    # pass 1: dq over the forward's (BH, n_q, n_k) grid
+    # pass 1: dq over the forward's (BH, n_q, n_k) grid; dead steps repeat
+    # the nearest live k block, so nothing is fetched for them
+    def kv_map(h, i, j):
+        return h // group, jnp.clip(j, *tiles.k_blocks(i)), 0
+
     dq = pl.pallas_call(
-        functools.partial(
-            _fa_bwd_dq_kernel, causal=causal, window=window,
-            block_q=block_q, block_k=block_k, n_k=n_k, kv_len=Sk,
-        ),
+        functools.partial(_fa_bwd_dq_kernel, tiles=tiles),
         grid=(BH, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda h, i, j, g=group: (h // g, j, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda h, i, j, g=group: (h // g, j, 0)),
+            pl.BlockSpec((1, block_k, hd), kv_map),
+            pl.BlockSpec((1, block_k, hd), kv_map),
             pl.BlockSpec((1, block_q, hd), lambda h, i, j: (h, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda h, i, j: (h, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda h, i, j: (h, 0, i)),
@@ -205,36 +211,26 @@ def flash_attention_bwd_flat(
     )(q, k, v, do, lse_p, delta)
 
     # pass 2: dk/dv per kv head; the inner axis walks the G query heads of
-    # the group times the q blocks, accumulating into one VMEM tile
+    # the group times the q blocks, accumulating into one VMEM tile. Within
+    # one query head, dead steps repeat the nearest live q block.
     n_inner = group * n_q
 
-    def _qh(h, t, g=group, nq=n_q):
-        return h * g + t // nq
+    def q_map(h, jk, t):
+        qi = jnp.clip(t % n_q, *tiles.q_blocks(jk))
+        return h * group + t // n_q, qi, 0
+
+    def row_map(h, jk, t):
+        qh, qi, _ = q_map(h, jk, t)
+        return qh, 0, qi
 
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _fa_bwd_dkv_kernel, causal=causal, window=window,
-            block_q=block_q, block_k=block_k, n_q=n_q, n_inner=n_inner,
-            kv_len=Sk,
-        ),
+        functools.partial(_fa_bwd_dkv_kernel, tiles=tiles, n_inner=n_inner),
         grid=(BKv, n_k, n_inner),
         in_specs=[
-            pl.BlockSpec(
-                (1, block_q, hd),
-                lambda h, jk, t, nq=n_q: (_qh(h, t), t % nq, 0),
-            ),
-            pl.BlockSpec(
-                (1, block_q, hd),
-                lambda h, jk, t, nq=n_q: (_qh(h, t), t % nq, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q),
-                lambda h, jk, t, nq=n_q: (_qh(h, t), 0, t % nq),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q),
-                lambda h, jk, t, nq=n_q: (_qh(h, t), 0, t % nq),
-            ),
+            pl.BlockSpec((1, block_q, hd), q_map),
+            pl.BlockSpec((1, block_q, hd), q_map),
+            pl.BlockSpec((1, 1, block_q), row_map),
+            pl.BlockSpec((1, 1, block_q), row_map),
             pl.BlockSpec((1, block_k, hd), lambda h, jk, t: (h, jk, 0)),
             pl.BlockSpec((1, block_k, hd), lambda h, jk, t: (h, jk, 0)),
         ],

@@ -41,6 +41,12 @@ FA_CASES = [
     (1, 128, 2, 2, 16, True, 32),    # sliding window
     (1, 130, 2, 2, 16, True, 0),     # non-multiple-of-block S
     (1, 250, 1, 2, 16, True, 64),    # non-multiple S + window
+    # from S = 512: dead tiles above the diagonal and left of a window band
+    (1, 1024, 1, 4, 32, True, 0),    # G=4: the dkv q-block clamp per member
+    (1, 1024, 2, 4, 16, True, 200),  # G=4, dead tiles on both band sides
+    (1, 640, 1, 2, 128, True, 0),    # head_dim 128
+    (1, 520, 1, 4, 16, True, 128),   # non-multiple S, window = one block
+    (1, 512, 2, 2, 16, False, 0),    # non-causal: every tile full
 ]
 
 
