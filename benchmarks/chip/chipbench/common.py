@@ -179,6 +179,20 @@ class CellRun:
             out.append((m["name"], m["unit"]))
         return out
 
+    def end_to_end_metrics(self, readings: Dict[str, float]) -> Dict[str, Any]:
+        """The result's metrics of an untraced run: the driver's readings of
+        this cell's end-to-end metrics and the set-up clock, each with its
+        unit; readings of other metrics are dropped. A metric of the cell
+        with no reading is an error."""
+        want = dict(self.end_to_end_names())
+        out = {k: {"value": float(v), "unit": want[k]}
+               for k, v in readings.items() if k in want}
+        out["setup_s"] = {"value": float(self.setup_s), "unit": "s"}
+        missing = sorted(set(want) - set(out))
+        if missing:
+            raise ValueError(f"no reading of {missing}")
+        return out
+
     def per_layer_specs(self) -> List[Dict[str, Any]]:
         """The per-layer metrics of this cell: each names its cells."""
         for m in self.bench["per_layer"]:

@@ -339,7 +339,10 @@ def served_gaps(c: Dict[str, Any], params, picked: List[Rec], modes=("f32",),
     """Widest gap, over every served token of ``picked``, by which its f32
     reference logit lies below the reference's best at that position.
     With ``"fp8"`` in ``modes`` also the control's: the gap of the token the
-    fp8 reference puts first."""
+    fp8 reference puts first. Tokens, and the rows read (the last one
+    repeated), are padded to a multiple of ``bucket``, so that a few
+    programs serve every request and the compile cache finds them in
+    later runs."""
     import jax.numpy as jnp
 
     from references import dense_decoder as ref
@@ -351,8 +354,10 @@ def served_gaps(c: Dict[str, Any], params, picked: List[Rec], modes=("f32",),
         toks = np.zeros(L, np.int32)
         toks[: len(seq)] = seq
         rows = np.arange(len(r.prompt) - 1, len(seq))
+        padded = np.full(-(-len(rows) // bucket) * bucket, rows[-1])
+        padded[: len(rows)] = rows
         lg = np.asarray(ref.logits_at(c, params, jnp.asarray(toks),
-                                      jnp.asarray(rows), "f32"))
+                                      jnp.asarray(padded), "f32"))[: len(rows)]
         best = lg.max(axis=1)
         served = np.asarray(r.out)
         worst["f32"] = max(worst["f32"], float(np.max(
@@ -364,7 +369,7 @@ def served_gaps(c: Dict[str, Any], params, picked: List[Rec], modes=("f32",),
                 best - lg[np.arange(len(rows)), alt])))
         if "fp8" in modes:
             lq = np.asarray(ref.logits_at(c, params, jnp.asarray(toks),
-                                          jnp.asarray(rows), "fp8"))
+                                          jnp.asarray(padded), "fp8"))[: len(rows)]
             pick = lq.argmax(axis=1)
             worst["fp8"] = max(worst["fp8"], float(np.max(
                 best - lg[np.arange(len(rows)), pick])))

@@ -101,13 +101,10 @@ def main() -> None:
         metrics = tr["metrics"]
         extra = {"busy_s": tr["busy_s"], "window_s": tr["window_s"]}
     else:
-        want = dict(run.end_to_end_names())
-        metrics = {k: {"value": float(v), "unit": want[k]}
-                   for k, v in res["metrics"].items() if k in want}
-        metrics["setup_s"] = {"value": float(run.setup_s), "unit": "s"}
-        missing = sorted(set(want) - set(metrics))
-        if missing:
-            fail(f"no reading of {missing}")
+        try:
+            metrics = run.end_to_end_metrics(res["metrics"])
+        except ValueError as e:
+            fail(str(e))
         extra = {}
     checks = {name: {"value": float(v), "limit": float(lim)}
               for name, v, lim in res["checks"]}

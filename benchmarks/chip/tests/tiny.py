@@ -22,6 +22,8 @@ for p in (os.path.join(REPO, "src"), CHIP):
 TINY_LIMITS = {"serve": {"served_gap": 0.08},
                "train": {"loss_gap": 4e-4, "grad_gap": 2e-3,
                          "update_gap": 8e-4}}
+REAL_CELLS = {"serve": "granite-8b.serve16.chat",
+              "train": "granite-8b.train2.seq2k"}
 TINY = {"hidden_size": 256, "num_attention_heads": 4, "num_key_value_heads": 1,
         "head_dim": 64, "intermediate_size": 512, "vocab_size": 1024,
         "num_hidden_layers": 2, "program_size": "reduced"}
@@ -32,9 +34,12 @@ def _load(path):
         return json.load(f)
 
 
-def make_root(tmp, kind: str):
+def make_root(tmp, kind: str, real: bool = False):
     """A benchmark root under ``tmp`` holding one tiny cell of ``kind``
-    (``serve`` or ``train``); returns (root, bench dict, cell name)."""
+    (``serve`` or ``train``); returns (root, bench dict, cell name). With
+    ``real`` the cell is ``REAL_CELLS[kind]`` of the repository's own
+    ``BENCHMARK.json``, its metric lists included, and the tiny files
+    stand under its configuration's and mix's names."""
     root = str(tmp)
     os.makedirs(os.path.join(root, "configs"), exist_ok=True)
     os.makedirs(os.path.join(root, "traffic"), exist_ok=True)
@@ -56,22 +61,27 @@ def make_root(tmp, kind: str):
         c["train"]["fused_backward"] = False
         mix = _load(os.path.join(CHIP, "traffic", "seq2k.json"))
         mix.update(global_batch=4, seq_len=64, distinct_batches=4)
-    c["name"] = "tiny-" + kind
+    if real:
+        bench = _load(os.path.join(REPO, "BENCHMARK.json"))
+        cell = REAL_CELLS[kind]
+        w = {w["name"]: w for w in bench["workloads"]}[cell]
+        c["name"], mix_name = w["config"], w["traffic"]
+    else:
+        cell, c["name"], mix_name = "tiny." + kind, "tiny-" + kind, "tiny-" + kind
+        bench = {"configs": [{"name": c["name"]}],
+                 "workloads": [{"name": cell, "config": c["name"],
+                                "traffic": mix_name, "chips": 1}],
+                 "end_to_end": [], "per_layer": []}
     c["limits"] = TINY_LIMITS[kind]
     with open(os.path.join(root, "configs", c["name"] + ".json"), "w") as f:
         json.dump(c, f)
-    with open(os.path.join(root, "traffic", "tiny-" + kind + ".json"), "w") as f:
+    with open(os.path.join(root, "traffic", mix_name + ".json"), "w") as f:
         json.dump(mix, f)
-    cell = "tiny." + kind
-    bench = {"configs": [{"name": c["name"]}],
-             "workloads": [{"name": cell, "config": c["name"],
-                            "traffic": "tiny-" + kind, "chips": 1}],
-             "end_to_end": [], "per_layer": []}
     return root, bench, cell
 
 
 def run_tiny(tmp, kind: str, seed: int = 7, seconds: float = 2.0,
-             control: bool = False):
+             control: bool = False, real: bool = False):
     """Drive one tiny cell on the CPU; returns (driver result, CellRun)."""
     import importlib
 
@@ -80,7 +90,7 @@ def run_tiny(tmp, kind: str, seed: int = 7, seconds: float = 2.0,
     from chipbench.common import CellRun
     from peaks import PEAKS
 
-    root, bench, cell = make_root(tmp, kind)
+    root, bench, cell = make_root(tmp, kind, real)
     run = CellRun(bench, cell, seed, seconds, False, control=control,
                   root=root)
     run.use_devices(jax.devices()[:1], PEAKS["TPU v5 lite"])
