@@ -4,12 +4,14 @@ readers, and the result line.
 
 Everything a cell is made of is found by name: ``BENCHMARK.json`` names the
 cell's configuration and traffic mix, ``configs/<config>.json`` holds the
-configuration, ``traffic/<mix>.json`` the mix (its ``driver`` names the
-module of this package that drives it), ``metrics/<metric>.py`` each
-per-layer metric's reader.
+configuration (its ``reference`` names the plain reference,
+``references/<reference>.py``), ``traffic/<mix>.json`` the mix (its
+``driver`` names the module of this package that drives it),
+``metrics/<metric>.py`` each per-layer metric's reader.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
@@ -59,6 +61,18 @@ def load_json(path: str) -> Dict[str, Any]:
         return json.load(f)
 
 
+def reference_module(name: str):
+    """The plain reference ``references/<name>.py`` that a configuration
+    names; a missing one is an error that names it."""
+    try:
+        return importlib.import_module("references." + name)
+    except ModuleNotFoundError as e:
+        if e.name not in ("references", "references." + name):
+            raise
+        raise ValueError(f"the configuration names the reference {name!r}, "
+                         f"and there is no references/{name}.py") from None
+
+
 def load_module(path: str, name: str):
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
@@ -82,6 +96,7 @@ class CellRun:
             root, "configs", self.cell["config"] + ".json"))
         self.mix = load_json(os.path.join(
             root, "traffic", self.cell["traffic"] + ".json"))
+        self.reference = reference_module(self.config["reference"])
         self.root = root
         self.seed = int(seed)
         self.seconds = float(seconds)
@@ -119,18 +134,13 @@ class CellRun:
         return cfg
 
     def check_arch(self, cfg) -> None:
-        """The program runs the configuration as its file states it."""
+        """The program runs the configuration as its file states it: each
+        pair of file key and program field that the configuration's
+        reference reads (its ``ARCH_KEYS``) agrees."""
         c = self.config
-        want = {
-            "d_model": c["hidden_size"], "n_heads": c["num_attention_heads"],
-            "n_kv_heads": c["num_key_value_heads"], "head_dim": c["head_dim"],
-            "d_ff": c["intermediate_size"], "vocab_size": c["vocab_size"],
-            "n_layers": c["num_hidden_layers"], "mlp_gated": c["mlp_gated"],
-            "norm": c["norm"], "rope_theta": c["rope_theta"],
-            "tie_embeddings": c["tie_word_embeddings"],
-        }
-        got = {k: getattr(cfg, k) for k in want}
-        bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
+        bad = {key: (getattr(cfg, field), c[key])
+               for key, field in self.reference.ARCH_KEYS
+               if getattr(cfg, field) != c[key]}
         if bad:
             raise ValueError(f"program config departs from the file: {bad}")
 
@@ -213,7 +223,9 @@ class CellRun:
             raise RuntimeError("the trace holds no TPU device plane")
         window_s = (w1 - w0) / 1e9
         busy = {k: tr.busy_ns(v) / 1e9 for k, v in dev.items()}
-        ctx = TraceContext(self, dev, host, window_s, busy)
+        asyn = {k: tr.clip(v, w0, w1) for k, v in data["async"].items()}
+        ctx = TraceContext(self, dev, asyn, data["kinds"], host, window_s,
+                           busy)
         metrics = {}
         for spec in self.per_layer_specs():
             mod = load_module(os.path.join(self.root, "metrics",
@@ -237,13 +249,15 @@ class CellRun:
 
 
 class TraceContext:
-    """What a per-layer reader gets: the window's device events per device,
-    the benchmark's host spans, the window's and each device's busy
-    seconds, the peaks, the configuration and mix, and the load
-    generator's work counts."""
+    """What a per-layer reader gets: the window's device events per device
+    (op line and async line) and its collectives' opcodes, the benchmark's
+    host spans, the window's and each device's busy seconds, the peaks, the
+    configuration and mix, and the load generator's work counts."""
 
-    def __init__(self, run: CellRun, dev, host, window_s, busy):
+    def __init__(self, run: CellRun, dev, asyn, kinds, host, window_s, busy):
         self.device = dev
+        self.async_ops = asyn
+        self.op_kinds = kinds
         self.host = host
         self.window_s = window_s
         self.busy_s = busy

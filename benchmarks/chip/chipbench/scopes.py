@@ -1,8 +1,9 @@
 """Program regions of the train step, read from the device trace.
 
 The program runs each region of its train step under a fixed
-``jax.named_scope`` (``REGIONS``). XLA keeps each HLO instruction's scope
-path as its ``op_name`` metadata, e.g.
+``jax.named_scope`` (``REGIONS``; a configuration's optional ``regions``
+list adds names that its own cell reads). XLA keeps each HLO instruction's
+scope path as its ``op_name`` metadata, e.g.
 ``jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/mlp/dot_general``,
 and the profiler stores every program's HLO, metadata included, in the
 trace's ``/host:metadata`` plane: one ``Hlo Proto`` stat per program. The
@@ -22,7 +23,7 @@ from __future__ import annotations
 import os
 import re
 from collections import defaultdict
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 from chipbench import trace
 from chipbench.common import HERE, load_json, log
@@ -35,26 +36,28 @@ _TRANSFORM = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
 
 
 # --------------------------------------------------------------- scope paths
-def region_of(path: str) -> Optional[str]:
-    """The innermost of ``REGIONS`` on a scope path, or None."""
+def region_of(path: str, regions: Sequence[str] = REGIONS) -> Optional[str]:
+    """The innermost of ``regions`` on a scope path, or None."""
     found = None
     for part in path.split("/"):
         m = _TRANSFORM.match(part)
         while m:
             part = m.group(1)
             m = _TRANSFORM.match(part)
-        if part in REGIONS:
+        if part in regions:
             found = part
     return found
 
 
-def region_map(paths: Dict[str, List[str]]) -> Optional[Dict[str, str]]:
+def region_map(paths: Dict[str, List[str]],
+               regions: Sequence[str] = REGIONS) -> Optional[Dict[str, str]]:
     """{instruction name: region} from {instruction name: [scope paths]}
     (one path per module that holds the name); None when no path names a
     region or one name maps to two regions."""
     out: Dict[str, str] = {}
     for op, ps in paths.items():
-        found = {r for r in map(region_of, ps) if r is not None}
+        found = {r for r in (region_of(p, regions) for p in ps)
+                 if r is not None}
         if len(found) > 1:
             log(f"scopes: {op} maps to {sorted(found)}: not read")
             return None
@@ -197,7 +200,8 @@ def seconds(ctx) -> Optional[Dict[Optional[str], float]]:
 
 def _seconds(ctx) -> Optional[Dict[Optional[str], float]]:
     path = cell_trace(ctx)
-    regions = region_map(op_paths(path)) if path else None
+    names = REGIONS + tuple(ctx.config.get("regions", ()))
+    regions = region_map(op_paths(path), names) if path else None
     if regions is None:
         log("scopes: the trace names no region of the train step")
         return None

@@ -27,7 +27,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from chipbench import counts, traffic, weights
-from chipbench.common import CellRun, Span
+from chipbench.common import CellRun, Span, reference_module
 
 
 @dataclasses.dataclass
@@ -345,8 +345,7 @@ def served_gaps(c: Dict[str, Any], params, picked: List[Rec], modes=("f32",),
     later runs."""
     import jax.numpy as jnp
 
-    from references import dense_decoder as ref
-
+    ref = reference_module(c["reference"])
     worst = {m: 0.0 for m in modes}
     for r in picked:
         seq = np.concatenate([r.prompt, np.asarray(r.out[:-1], np.int32)])

@@ -6,8 +6,9 @@ same call the window uses; the window then goes on with that object. The
 state is the benchmark's: f32 master weights made from the seed
 (``chipbench.weights``), zero optimizer moments.
 
-Compared with the reference, which follows the same steps with its own
-AdamW and clipping in f32 (``references``):
+Compared with the configuration's reference (``references/<reference>.py``),
+which follows the same steps with its own AdamW and clipping in f32, its
+state spread over the cell's devices:
 
 * ``loss_gap``: per step, |loss - reference| / |reference|, the worst step;
 * ``grad_gap``: the first gradient as the optimizer got it, read from the
@@ -27,7 +28,7 @@ from typing import Any, Dict, Sequence
 import numpy as np
 
 from chipbench import traffic, weights
-from chipbench.common import CellRun, Span
+from chipbench.common import CellRun, Span, reference_module
 
 
 def leaf_gaps(prog: Sequence[float], ref: Sequence[float], keep) -> float:
@@ -85,31 +86,57 @@ def _adam_update(job, t: int):
                    donate_argnums=(0,))
 
 
+def reference_shardings(p_struct, devices):
+    """Where the reference keeps its f32 state on the cell's ``devices``: a
+    1-D mesh over them; each param leaf split along its last dimension that
+    their number divides (else whole on each), the batch by rows. On one
+    device every leaf is whole there."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(devices), ("d",))
+    n = len(devices)
+
+    def leaf(s):
+        dims = [i for i, k in enumerate(s.shape) if n > 1 and k % n == 0]
+        spec = [None] * len(s.shape)
+        if dims:
+            spec[dims[-1]] = "d"
+        return NamedSharding(mesh, P(*spec))
+
+    return (jax.tree.map(leaf, p_struct),
+            lambda rows: NamedSharding(mesh, P("d" if rows % n == 0 else None)))
+
+
 def reference_steps(c, job, p_struct, seed: int, batches, mode: str = "f32",
-                    rows=None) -> Dict[str, Any]:
+                    rows=None, devices=None) -> Dict[str, Any]:
     """The reference trainer over ``batches`` (each a host dict), from the
     generated weights: losses, first-gradient leaf norms as clipped, and
     leaf norms of the change after all the steps. ``rows`` keeps only those
-    rows of each batch (a planted fault). On the device: the params and one
-    clipped gradient per step are held, so two steps fit beside a
-    published-width layer stack."""
+    rows of each batch (a planted fault). The params and one clipped
+    gradient per step are held, spread over ``devices`` (the cell's; the
+    default device if None) by ``reference_shardings``, so two steps fit
+    beside a published-width layer stack."""
     import jax
     import jax.numpy as jnp
 
-    from references import dense_decoder as ref
-
+    ref = reference_module(c["reference"])
+    p_shard, batch_shard = reference_shardings(
+        p_struct, devices or jax.devices()[:1])
     sq = jax.jit(lambda t: [jnp.sum(jnp.square(x)) for x in
                             jax.tree_util.tree_leaves(t)])
     scale_by = jax.jit(lambda t, s: jax.tree.map(lambda x: x * s, t),
                        donate_argnums=(0,))
-    params = weights.generate(p_struct, seed, jnp.float32)
+    params = weights.generate(p_struct, seed, jnp.float32, p_shard)
     losses, grads = [], []
     for k, b in enumerate(batches):
         toks, labs = b["tokens"], b["labels"]
         if rows is not None:
             toks, labs = toks[rows], labs[rows]
-        loss, g = ref.loss_and_grad(c, params, jnp.asarray(toks),
-                                    jnp.asarray(labs), mode, job["z_loss"])
+        put = batch_shard(toks.shape[0])
+        loss, g = ref.loss_and_grad(c, params, jax.device_put(toks, put),
+                                    jax.device_put(labs, put), mode,
+                                    job["z_loss"])
         losses.append(float(loss))
         gnorm = float(np.sqrt(sum(float(x) for x in sq(g))))
         grads.append(scale_by(g, min(1.0, job["grad_clip"] / (gnorm + 1e-9))))
@@ -257,8 +284,8 @@ def run_cell(run: CellRun) -> Dict[str, Any]:
     gc.collect()
     n = run.mix["check_steps"]
     t0 = time.perf_counter()
-    ref = reference_steps(run.config, run.config["train"], p_struct,
-                          run.seed, host[:n])
+    args = (run.config, run.config["train"], p_struct, run.seed, host[:n])
+    ref = reference_steps(*args, devices=run.devices)
     run.note(f"reference: {n} steps in {time.perf_counter() - t0:.1f} s; "
              f"losses program {prog['losses']} reference {ref['losses']}")
     gaps = compare(prog, ref)
@@ -266,13 +293,10 @@ def run_cell(run: CellRun) -> Dict[str, Any]:
     checks = [(k, gaps[k], lim[k]) for k in ("loss_gap", "grad_gap",
                                              "update_gap")]
     if run.control:
-        ctl = compare(reference_steps(run.config, run.config["train"],
-                                      p_struct, run.seed, host[:n], "fp8"),
-                      ref)
+        ctl = compare(reference_steps(*args, "fp8", devices=run.devices), ref)
         B = run.mix["global_batch"]
-        half = compare(reference_steps(run.config, run.config["train"],
-                                       p_struct, run.seed, host[:n],
-                                       rows=slice(0, B // 2)), ref)
+        half = compare(reference_steps(*args, rows=slice(0, B // 2),
+                                       devices=run.devices), ref)
         checks += [("control_fp8_" + k, ctl[k], lim[k]) for k in ctl]
         checks += [("fault_half_batch_" + k, half[k], lim[k]) for k in half]
     ok = all(v <= l for _, v, l in checks[:3])

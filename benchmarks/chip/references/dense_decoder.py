@@ -36,6 +36,18 @@ import jax
 import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+# (configuration file key, program ``ArchConfig`` field): what this
+# reference reads of the architecture, so what the harness holds the
+# program's config to (``CellRun.check_arch``)
+ARCH_KEYS = (
+    ("hidden_size", "d_model"), ("num_attention_heads", "n_heads"),
+    ("num_key_value_heads", "n_kv_heads"), ("head_dim", "head_dim"),
+    ("intermediate_size", "d_ff"), ("vocab_size", "vocab_size"),
+    ("num_hidden_layers", "n_layers"), ("mlp_gated", "mlp_gated"),
+    ("norm", "norm"), ("rope_theta", "rope_theta"),
+    ("tie_word_embeddings", "tie_embeddings"),
+)
 E4M3_MAX = 448.0
 
 

@@ -64,8 +64,11 @@ def main() -> None:
     from peaks import peaks_for
 
     bench = load_json(bench_path)
-    run = CellRun(bench, args.workload, args.seed, args.seconds,
-                  bool(args.trace), control=args.control, t_start=T_START)
+    try:
+        run = CellRun(bench, args.workload, args.seed, args.seconds,
+                      bool(args.trace), control=args.control, t_start=T_START)
+    except (KeyError, ValueError) as e:
+        fail(str(e))
     chips = int(run.cell["chips"])
 
     import jax

@@ -174,3 +174,27 @@ def test_cell_trace_is_the_cells_own(tmp_path, monkeypatch):
     assert {t: scopes.cell_trace(ctx(t)) for t in mixes} == {
         t: str(p) for t, p in want.items()}
     assert scopes.cell_trace(types.SimpleNamespace(config=cfg, mix={})) is None
+
+
+def test_a_configs_own_region_is_read_only_when_listed(monkeypatch):
+    """Half of the ``mlp`` ops put under an inner ``experts`` scope: a
+    configuration that lists ``experts`` among its ``regions`` reads them as
+    a region of their own, carved out of ``mlp``; one that does not reads
+    exactly what it read before."""
+    d, dev = _fixture()
+    mlp_ops = sorted(op for op, ps in d["paths"].items()
+                     if scopes.region_of(ps[0]) == "mlp")
+    moved = set(mlp_ops[::2])
+    paths = {op: [p.replace("/mlp/", "/mlp/experts/") for p in ps]
+             if op in moved else ps for op, ps in d["paths"].items()}
+    before = scopes.seconds(_ctx(d, dev, monkeypatch))
+    plain = scopes.seconds(_ctx(dict(d, paths=paths), dev, monkeypatch))
+    assert plain == before and "experts" not in plain
+    listed = dict(d, paths=paths, config=dict(d["config"], regions=["experts"]))
+    got = scopes.seconds(_ctx(listed, dev, monkeypatch))
+    want = sum(dur for ev in dev.values() for name, _, dur in trace.leaves(ev)
+               if name in moved) / 1e9
+    assert got["experts"] == pytest.approx(want, rel=1e-12) and want > 0
+    assert got["mlp"] + got["experts"] == pytest.approx(before["mlp"], rel=1e-12)
+    assert {k: v for k, v in got.items() if k not in ("mlp", "experts")} == {
+        k: v for k, v in before.items() if k != "mlp"}

@@ -100,3 +100,96 @@ def test_train_fixture_numbers():
     # no kernel event holds another: their times add
     assert len(trace.leaves(fa)) == len(fa)
     assert trace.busy_ns(dev[0]) / (w1 - w0) > 0.95
+
+
+def test_collectives_by_hand():
+    assert trace.subtract([(0.0, 10.0), (20.0, 30.0)], [(5.0, 25.0)]) == 10.0
+    assert trace.subtract([(0.0, 10.0)], []) == 10.0
+    assert trace.subtract([(2.0, 4.0)], [(0.0, 1.0), (1.5, 5.0)]) == 0.0
+    assert trace.op_kind("%psum.7 = bf16[1,8]{1,0:T(8,128)(2,1)} all-reduce("
+                         "bf16[1,8]{1,0} %fusion.3), to_apply=%add") == "all-reduce"
+    assert trace.op_kind(
+        "%collective-permute-start.2 = (bf16[8]{0:T(256)}, bf16[8]{0:T(256)"
+        "S(1)}, u32[]{:S(2)}) collective-permute-start(bf16[8]{0} %x)"
+    ) == "collective-permute-start"
+    assert trace.op_kind("fusion.3") == "fusion.3"
+    ops = [("while.3", 0.0, 400.0),                    # a loop: a container
+           ("fusion.1", 0.0, 100.0),
+           ("all-reduce.1", 100.0, 50.0),              # sync: 50 exposed
+           ("collective-permute-start.1", 150.0, 5.0),
+           ("fusion.2", 160.0, 40.0),
+           ("collective-permute-done.1", 230.0, 10.0),  # 150-240: 10 + 40
+           ("fusion.3", 300.0, 50.0),
+           ("psum.7", 385.0, 10.0)]                    # an all-reduce by kind
+    asyn = [("all-gather-start.2", 320.0, 60.0),       # 350-380 exposed
+            ("copy-start.1", 0.0, 400.0)]              # no collective
+    kinds = {"psum.7": "all-reduce"}
+    spans = trace.collective_spans(ops, asyn, kinds)
+    assert sorted((s, d) for _, s, d in spans) == [
+        (100.0, 50.0), (150.0, 90.0), (320.0, 60.0), (385.0, 10.0)]
+    ctx = types.SimpleNamespace(device={0: ops, 1: [("fusion.9", 0.0, 400.0)]},
+                                async_ops={0: asyn}, op_kinds={0: kinds},
+                                window_s=400e-9)
+    assert trace.collective_exposed(ctx) == pytest.approx(100 * (140 / 400) / 2)
+    ctx.op_kinds = {}                                # psum.7 is then compute
+    assert trace.collective_exposed(ctx) == pytest.approx(100 * (130 / 400) / 2)
+    ctx.device, ctx.async_ops = {0: ops[:2]}, {}
+    assert trace.collective_exposed(ctx) is None     # nothing to read
+
+
+def _pp_fixture():
+    with open(os.path.join(FIX, "trace_pp2tp2.json")) as f:
+        d = json.load(f)
+    as_events = lambda t: {int(k): [tuple(e) for e in v] for k, v in t.items()}  # noqa: E731
+    return (d["window"], as_events(d["device"]), as_events(d["async"]),
+            {int(k): v for k, v in d["kinds"].items()})
+
+
+def _sweep_exposed(ops, kinds):
+    """Exposed collective ns by a sweep over the innermost ops' boundaries:
+    a count of collective-permutes in flight (up at a start's beginning,
+    down at a done's end), of synchronous collectives and of compute ops."""
+    pts = []
+    for name, s, d in trace.leaves(ops):
+        kind = kinds.get(name, "compute")
+        if kind.endswith("-start"):
+            pts.append((s, "flight", 1))
+        elif kind.endswith("-done"):
+            pts.append((s + d, "flight", -1))
+        else:
+            key = "sync" if kind != "compute" else "compute"
+            pts += [(s, key, 1), (s + d, key, -1)]
+    pts.sort(key=lambda p: (p[0], -p[2]))
+    depth = {"flight": 0, "sync": 0, "compute": 0}
+    exposed, last = 0.0, None
+    for t, key, step in pts:
+        if last is not None and (depth["flight"] or depth["sync"]) \
+                and not depth["compute"]:
+            exposed += t - last
+        depth[key] += step
+        last = t
+    return exposed
+
+
+def test_collective_exposed_on_a_four_chip_trace():
+    """Chip 0 by hand (ns from the stretch's start): the forward permute
+    from 0 (its start blocks until 136,809) to its done's end at 497,035,
+    the backward one from 136,811 to 856,572, a copy inside them from
+    136,854 to 301,632, and the TP all-reduce ``psum.184`` alone from
+    2,345,960 to 2,732,783: (856,572 - 164,778) + 386,823 = 1,078,617 ns
+    exposed of 2,800,000. The other chips against a plain sweep."""
+    (a, b), dev, asyn, kinds = _pp_fixture()
+    spans = trace.union(trace.collective_spans(dev[0], asyn[0], kinds[0]))
+    assert [(s - a, e - a) for s, e in spans] == [
+        (0.0, 856572.0), (2345960.0, 2732783.0)]
+    assert kinds[0]["psum.184"] == "all-reduce"
+    ctx = types.SimpleNamespace(device=dev, async_ops=asyn, op_kinds=kinds,
+                                window_s=(b - a) / 1e9)
+    exposed = {d: _sweep_exposed(ops, kinds[d]) for d, ops in dev.items()}
+    assert exposed[0] == 1078617.0
+    assert len(dev) == 4 and all(0 < v < b - a for v in exposed.values())
+    assert trace.collective_exposed(ctx) == pytest.approx(
+        100 * sum(exposed.values()) / 4 / (b - a), rel=1e-12)
+    # the async line alone (chip 0 only) covers the same permutes
+    assert trace.union(e for e in asyn[0] if trace.is_collective(e[0])) == \
+        [(a, a + 856572.0)]
