@@ -49,16 +49,23 @@ def paged_attention(
     v_pages: jax.Array,
     tables: jax.Array,
     lengths: jax.Array,
+    *,
+    active=None,
     **kw,
 ) -> jax.Array:
     """Decode attention: the T=1 call of :func:`paged_prefill_attention`,
     one query per slot at position ``lengths - 1``. q: (B, Kv, G, hd)
-    pre-scaled; pools ([L,] N, Kv, page, hd) -> (B, Kv, G, hd). Keyword
+    pre-scaled; pools ([L,] N, Kv, page, hd) -> (B, Kv, G, hd). ``active``
+    (B,) bool makes inactive slots zero-row calls, which the kernel does
+    not walk (their output is garbage by contract). Other keyword
     arguments as for :func:`paged_prefill_attention`."""
     lengths = lengths.astype(jnp.int32)
+    q_len = (
+        jnp.ones_like(lengths) if active is None
+        else active.astype(jnp.int32)
+    )
     out = paged_prefill_attention(
-        q[:, None], k_pages, v_pages, tables, lengths - 1,
-        jnp.ones_like(lengths), **kw,
+        q[:, None], k_pages, v_pages, tables, lengths - 1, q_len, **kw,
     )
     return out[:, 0]
 

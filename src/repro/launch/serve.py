@@ -116,6 +116,19 @@ def _replay_cli(args, cfg, eng) -> None:
         f"ticks of arrival); completed={completed}; "
         f"preemptions={s.get('evictions', 0)}; deferred_ticks={deferred}"
     )
+    _print_kv_walk(s)
+
+
+def _print_kv_walk(s) -> None:
+    """The share of the KV horizon the paged kernel walked (kernel on)."""
+    if s.get("paged_kv_blocks_horizon"):
+        walked, horizon = (
+            s["paged_kv_blocks_walked"], s["paged_kv_blocks_horizon"]
+        )
+        print(
+            f"  paged-kernel kv walk: {walked}/{horizon} blocks "
+            f"({walked / horizon:.3f} of the horizon)"
+        )
 
 
 def main() -> None:
@@ -326,6 +339,7 @@ def main() -> None:
             f"bytes/request={np.mean(per_req):.0f} (mean over {len(per_req)}), "
             f"capacity_factor_vs_bf16={cap_factor:.2f}x"
         )
+        _print_kv_walk(s)
         if args.spec:
             print(
                 f"  spec: k={args.spec} drafter={args.drafter} "

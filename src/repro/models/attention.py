@@ -395,8 +395,9 @@ def attention_decode_paged(
     x: (B, 1, d); cache: {"kp","vp"} ([L,] N, Kv, page, hd) as in
     ``attention_prefill_paged`` (``layer``); tables: (B, P) int32;
     lengths: (B,) int32 tokens already cached per slot (the new token's
-    position); active: (B,) bool — inactive slots write to the null page and
-    their output is garbage by contract (the serve engine discards it).
+    position); active: (B,) bool — inactive slots write to the null page,
+    the kernel walks none of their pages, and their output is garbage by
+    contract (the serve engine discards it).
 
     Unlike ``attention_decode``'s ring buffer, every slot here has its own
     position, so continuous batching can mix requests at different depths.
@@ -444,8 +445,8 @@ def attention_decode_paged(
     q = q.reshape(B, n_kv, G, head_dim) * (head_dim ** -0.5)
     out = paged_attention(
         q, new_cache["kp"], new_cache["vp"], tables, lengths + 1,
-        window=window, use_kernel=use_kernel, mesh=mesh, layer=layer,
-        **scales,
+        active=active, window=window, use_kernel=use_kernel, mesh=mesh,
+        layer=layer, **scales,
     )
     out = out.astype(dtype).reshape(B, 1, n_heads * head_dim)
     return out @ p["wo"].astype(dtype), new_cache

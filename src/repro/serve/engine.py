@@ -100,6 +100,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels.paged_attention.kernel import walked_blocks
 from repro.models import (
     Runtime,
     decode_step_paged,
@@ -108,7 +109,7 @@ from repro.models import (
 )
 from repro.models.layers import Params
 from repro.models.lm import verify_step_paged
-from repro.models.stack import write_prefill_to_pool
+from repro.models.stack import layer_specs, write_prefill_to_pool
 from repro.serve import dense as dense_mod
 from repro.serve import spec as spec_mod
 from repro.serve.pool import PagePool, PoolExhausted
@@ -461,6 +462,20 @@ class ServeEngine:
                 }
             self._dev.update(extras)
             self.stats["kv_pool_bytes_per_device"] = self.kv_pool_bytes_per_device()
+            if self.rt.use_paged_kernel:
+                # KV blocks the paged kernel walks, and those a walk over
+                # every slot's whole horizon would cover, summed over the
+                # target model's attention layers (host-side, per tick)
+                self.stats["paged_kv_blocks_walked"] = 0
+                self.stats["paged_kv_blocks_horizon"] = 0
+                windows = [
+                    s.window for s in layer_specs(
+                        cfg, seq_len=engine.max_len,
+                        long_variant=self.rt.long_variant,
+                    )
+                ]
+                # (window, layers that share it)
+                self._windows = [(w, windows.count(w)) for w in set(windows)]
             # key only on what the trace depends on (seed/policy are
             # host-side; self.rt already folds in use_kernel)
             ckey = (
@@ -1278,6 +1293,7 @@ class ServeEngine:
         # commit only if they equal the argmax — the correct token anyway.
         tokens = np.concatenate([tok[:, None], drafts], axis=1)
         q_len = np.where(active, k + 1, 0).astype(np.int32)
+        self._count_kv_walk(False, verify=(lengths, q_len))
         caches, g, a = self._verify_fn(
             self.params, d["caches"], d["tables"], d["lengths"],
             self._place(jnp.asarray(tokens, jnp.int32)),
@@ -1387,6 +1403,7 @@ class ServeEngine:
             # by tick never changes tokens
             if self.ecfg.spec_tokens:
                 return self._spec_step()
+            self._count_kv_walk(decode=True)
             return self._run_chunk()
         slot_id, slot = min(pf, key=lambda kv: kv[1].order)
         req = slot.req
@@ -1405,6 +1422,7 @@ class ServeEngine:
         decoding = any(
             s is not None and s.phase == "decode" for s in self._slots
         )
+        self._count_kv_walk(decoding, chunk=(start, n))
         if decoding:
             (
                 caches, lengths, remaining, tok, steps, emits, pf_logits
@@ -1433,6 +1451,45 @@ class ServeEngine:
         else:
             d["lengths"] = d["lengths"].at[slot_id].set(slot.pf_next)
         return np.asarray(emits), np.asarray(d["remaining"])
+
+    def _count_kv_walk(self, decode: bool, chunk=None, verify=None) -> None:
+        """Add one tick's paged-kernel calls to the walk counters, from the
+        slot positions the host tracks: the decode scan's ``inner_steps``
+        calls over every slot, a prefill chunk ``(start, rows)``, a verify
+        call ``(starts, q_lens)``."""
+        if "paged_kv_blocks_walked" not in self.stats:
+            return
+        ecfg, B = self.ecfg, self.ecfg.max_slots
+        if decode:
+            pos = np.zeros(B, np.int64)   # next position of a decoding slot
+            left = np.zeros(B, np.int64)  # tokens it still has to emit
+            for i, s in enumerate(self._slots):
+                if s is not None and s.phase == "decode":
+                    n_out = len(self._outputs[s.rid])
+                    pos[i] = self._prompt_total(s.req) + n_out - 1
+                    left[i] = s.req.max_new - n_out
+            # the scan's inner_steps calls, one row of slots each
+            step = np.arange(ecfg.inner_steps)[:, None]
+            self._add_kv_walk(pos + step, (left > step).astype(np.int64), 1)
+        if chunk is not None:
+            self._add_kv_walk(
+                np.array([chunk[0]]), np.array([chunk[1]]), ecfg.chunk_tokens
+            )
+        if verify is not None:
+            self._add_kv_walk(*verify, ecfg.spec_tokens + 1)
+
+    def _add_kv_walk(self, start: np.ndarray, q_len: np.ndarray, T: int):
+        """Kernel calls of ``T`` query rows over the slots in ``start`` (any
+        shape), in every attention layer."""
+        cfg = self.cfg
+        for window, n_layers in self._windows:
+            walked, horizon = walked_blocks(
+                start, q_len, rows=T * (cfg.n_heads // cfg.n_kv_heads),
+                hd=cfg.head_dim, page=self.ecfg.page_size,
+                n_pages=self._dev["tables"].shape[1], window=window,
+            )
+            self.stats["paged_kv_blocks_walked"] += walked * n_layers
+            self.stats["paged_kv_blocks_horizon"] += horizon * n_layers
 
     def _finish_prefill(self, slot_id: int, slot: _Slot, pf_logits) -> None:
         """Last chunk done: sample the first token (the request's TTFT) and
@@ -1761,6 +1818,7 @@ class ReplicatedServeEngine:
         for key in (
             "prompt_tokens", "prefix_lookups", "prefix_hits",
             "prefix_cached_tokens", "prefill_chunks",
+            "paged_kv_blocks_walked", "paged_kv_blocks_horizon",
             "spec_verify_calls", "spec_drafted_tokens",
             "spec_accepted_tokens",
         ):

@@ -43,7 +43,14 @@ CASES = [
     (3, 2, 4, 32, 8, 32, 4, [13, 27, 5]),  # GQA
     (2, 4, 2, 64, 16, 16, 4, [64, 33]),    # exact + off multiple
     (2, 2, 8, 32, 4, 32, 8, [1, 31]),      # single-token request
+    # granite widths (hd 128, page 16): decode blocks of 32 pages, so a
+    # 40-page horizon is one whole block and a partial one
+    (3, 2, 4, 128, 16, 16, 40, [1, 17, 150]),   # live context << horizon
+    (2, 1, 4, 128, 16, 70, 40, [512, 513]),     # ends on / past a block edge
+    (1, 2, 4, 128, 16, 48, 40, [640]),          # the whole horizon
+    (1, 1, 48, 128, 16, 24, 40, [300]),         # MQA at G = 48
 ]
+LONG = CASES[4]
 
 
 @pytest.mark.parametrize("case", CASES, ids=[str(c[:4]) for c in CASES])
@@ -123,7 +130,9 @@ def _quantize_case(q, kp, vp, kv_dtype):
 
 
 @pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
-@pytest.mark.parametrize("case", CASES[:2], ids=[str(c[:4]) for c in CASES[:2]])
+@pytest.mark.parametrize(
+    "case", CASES[:2] + [LONG], ids=[str(c[:4]) for c in CASES[:2] + [LONG]]
+)
 def test_quantized_kernel_matches_quantized_ref(case, kv_dtype):
     """Fused in-gather dequant inside the Pallas kernel == dequantizing in
     the jnp oracle: both read the same codes + scales, so they must agree
@@ -211,6 +220,13 @@ PREFILL_CASES = [
     (2, 4, 2, 2, 32, 8, 16, 4, [0, 5], [4, 3]),  # GQA, offset + ragged
     (1, 8, 1, 4, 32, 4, 24, 12, [13], [6]),      # mid-prompt chunk
     (3, 4, 2, 4, 16, 8, 32, 4, [0, 9, 17], [4, 2, 1]),  # mixed depths
+    # T*G > 128 starting mid-block (blocks of 32 pages), beside an inactive
+    # slot (q_len 0)
+    (2, 64, 1, 4, 128, 16, 100, 48, [600, 0], [64, 0]),
+    (1, 256, 1, 4, 128, 16, 40, 32, [200], [200]),   # a real chunk: 1024 rows
+    (1, 8, 1, 48, 128, 16, 24, 40, [290], [8]),      # MQA at G = 48
+    (1, 128, 4, 4, 128, 16, 40, 32, [300], [128]),   # 2 of 4 kv heads a program
+    (1, 32, 1, 48, 128, 16, 24, 24, [300], [29]),    # 1536 rows in 2 tiles
 ]
 
 
@@ -232,6 +248,8 @@ def test_prefill_kernel_matches_ref(case, window):
             np.asarray(out)[b, : qlens[b]], np.asarray(ref)[b, : qlens[b]],
             atol=2e-5, rtol=2e-5,
         )
+        if qlens[b] == 0:    # an inactive slot walks nothing: zeros
+            assert not np.any(np.asarray(out)[b])
 
 
 def test_prefill_kernel_interpret_mode_explicit():
@@ -283,3 +301,62 @@ def test_prefill_causality_ignores_future_garbage():
     np.testing.assert_allclose(
         np.asarray(out)[0], np.asarray(ref)[0], atol=2e-5, rtol=2e-5
     )
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+@pytest.mark.parametrize("window", [0, 6])
+@pytest.mark.parametrize("T", [1, 4])
+def test_dead_pages_are_never_read(T, window, kv_dtype):
+    """Each slot's table holds real (non-null) pages over the whole
+    horizon, and every key outside its live range — the pages past it, the
+    tail of its last live page, and with a window the keys before it —
+    holds NaN (int8: garbage codes under NaN scales). The valid rows match
+    the oracle on the clean pool: dead pages are neither copied nor let
+    into a result."""
+    B, Kv, G, hd, page, P = 3, 2, 4, 128, 16, 40
+    starts = np.array([0, 13, 146])
+    qlens = np.array([T, T, max(T - 2, 1)])
+    N = 1 + B * P
+    rng = np.random.RandomState(11)
+    q = jnp.asarray(rng.randn(B, T, Kv, G, hd), jnp.float32) * hd ** -0.5
+    kp = rng.randn(N, Kv, page, hd).astype(np.float32)
+    vp = rng.randn(N, Kv, page, hd).astype(np.float32)
+    tables = 1 + np.arange(B * P, dtype=np.int32).reshape(B, P)
+    pos = np.arange(P * page)
+    first = starts - window + 1 if window else np.zeros(B, np.int64)
+    dead = (pos[None] >= (starts + qlens)[:, None]) | (pos[None] < first[:, None])
+    quant = {}
+    if kv_dtype == "int8":
+        kc, vc, ks, vs = _quantize_case(q, jnp.asarray(kp), jnp.asarray(vp),
+                                        "int8")
+        clean = (kc, vc)
+        quant = dict(k_scale=ks, v_scale=vs)
+        kp, vp = np.asarray(kc).copy(), np.asarray(vc).copy()
+        poisoned = dict(k_scale=np.asarray(ks).copy(),
+                        v_scale=np.asarray(vs).copy())
+    else:
+        clean = (jnp.asarray(kp.copy()), jnp.asarray(vp.copy()))
+        poisoned = {}
+    for b in range(B):
+        for key in np.flatnonzero(dead[b]):
+            pid, slot = tables[b, key // page], key % page
+            if kv_dtype == "int8":
+                kp[pid, :, slot], vp[pid, :, slot] = 127, -127
+                for sc in poisoned.values():
+                    sc[pid, :, slot] = np.nan
+            else:
+                kp[pid, :, slot] = vp[pid, :, slot] = np.nan
+    tbl = jnp.asarray(tables)
+    st, ln = jnp.asarray(starts, jnp.int32), jnp.asarray(qlens, jnp.int32)
+    out = paged_prefill_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), tbl, st, ln, window=window,
+        use_kernel=True, **{k: jnp.asarray(v) for k, v in poisoned.items()},
+    )
+    ref = paged_prefill_attention_ref(
+        q, *clean, tbl, st, ln, window=window, **quant
+    )
+    for b in range(B):
+        np.testing.assert_allclose(
+            np.asarray(out)[b, : qlens[b]], np.asarray(ref)[b, : qlens[b]],
+            atol=2e-5, rtol=2e-5,
+        )

@@ -302,6 +302,64 @@ def test_engine_pallas_kernel_path(arch_state):
     np.testing.assert_array_equal(outs[True], outs[False])
 
 
+def test_engine_counts_paged_kv_walk(arch_state):
+    """The engine's count of the KV blocks the paged kernel walks, against
+    a hand count. granite-8b reduced: 2 layers, G 4, hd 64; page 8 over a
+    64-page horizon. Prefill chunks (256 rows x G 4 = 1024 score rows)
+    walk blocks of 128 keys, 4 to the horizon; decode (4 rows) one block
+    of 512 keys, the whole horizon.
+
+    tick 1: A's chunk [0, 256): 2 blocks; horizon 4
+    tick 2: A's chunk [256, 300): 3 blocks; horizon 4
+    tick 3: B's chunk [0, 10): 1 block; horizon 4; decode steps at 300,
+            301 for A (B inactive): 1 + 1 blocks; horizon 2 x 2 slots
+    tick 4: decode at 10, 11 for B: 1 + 1; horizon 2 x 2
+    tick 5: decode at 12 for B (its last token): 1; horizon 2 x 2
+    Per layer 11 blocks walked of 24, times 2 layers."""
+    cfg, params = arch_state("granite-8b")
+    rng = np.random.RandomState(8)
+    eng = ServeEngine(
+        cfg, params, RT,
+        EngineConfig(max_slots=2, page_size=8, num_pages=129, max_len=512,
+                     inner_steps=2, prefill_chunk=256, use_kernel=True),
+    )
+    prompts = [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in (300, 10)]
+    a, b = eng.submit(prompts[0], 3), eng.submit(prompts[1], 4)
+    ticks = 0
+    while eng.busy:
+        eng.step()
+        ticks += 1
+    out = eng.run_finalize()
+    assert ticks == 5
+    np.testing.assert_array_equal(out[a], _run_alone(cfg, params, prompts[0], 3))
+    np.testing.assert_array_equal(out[b], _run_alone(cfg, params, prompts[1], 4))
+    assert eng.stats["paged_kv_blocks_walked"] == 22
+    assert eng.stats["paged_kv_blocks_horizon"] == 48
+
+
+def test_engine_counts_paged_kv_walk_under_spec(arch_state):
+    """Speculative ticks: one (k+1)-row verify call a tick over both
+    slots, of which one is seated. A 64-token horizon is one block, so a
+    tick walks 1 block a layer of the 2 a full walk covers (2 layers)."""
+    cfg, params = arch_state("granite-8b")
+    prompt = np.random.RandomState(9).randint(0, cfg.vocab_size, (10,))
+    eng = ServeEngine(
+        cfg, params, RT,
+        EngineConfig(max_slots=2, page_size=8, num_pages=33, max_len=64,
+                     inner_steps=2, spec_tokens=3, use_kernel=True),
+    )
+    rid = eng.submit(prompt.astype(np.int32), 6)
+    ticks = 0
+    while eng.busy:
+        eng.step()
+        ticks += 1
+    out = eng.run_finalize()
+    np.testing.assert_array_equal(out[rid], _run_alone(cfg, params, prompt, 6))
+    assert eng.stats["paged_kv_blocks_walked"] == 2 * ticks
+    assert eng.stats["paged_kv_blocks_horizon"] == 4 * ticks
+
+
 def test_engine_dense_fallback_family(arch_state):
     cfg, params = arch_state("falcon-mamba-7b")
     rng = np.random.RandomState(6)

@@ -100,6 +100,39 @@ def test_paged_attention_compiles(one_chip, T, kv_dtype):
                  rows, rows, scale, scale)
 
 
+# (kv heads, G, head_dim, T, horizon pages, pool dtype): shapes whose VMEM the
+# kernel has to budget. granite-34b's MQA chunk is 12,288 query rows, tiled;
+# phi-3-vision's 32 and moonlight's 16 kv heads in decode are more than the
+# K/V buffers hold in one program; a quantized pool at a 128k-token horizon.
+WIDTH_CASES = {
+    "mqa-g48-chunk": (1, 48, 128, 256, 64, "bf16"),
+    "phi3v-decode": (32, 1, 96, 1, 64, "bf16"),
+    "moonlight-decode": (16, 1, 128, 1, 64, "bf16"),
+    "int8-128k-decode": (KV, G, HD, 1, 8192, "int8"),
+    "int8-128k-chunk": (KV, G, HD, 256, 8192, "int8"),
+}
+
+
+@pytest.mark.parametrize("case", list(WIDTH_CASES), ids=list(WIDTH_CASES))
+def test_paged_attention_widths_compile(one_chip, case):
+    Kv, G_, hd, T, P, kv_dtype = WIDTH_CASES[case]
+    B, N = 2, 1024
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    pool = S((N, Kv, PAGE, hd), POOL_DTYPES[kv_dtype])
+    scale = S((N, Kv, PAGE), jnp.float32)
+    rows = S((B,), jnp.int32)
+    quantized = kv_dtype != "bf16"
+    _compile(
+        lambda q, kp, vp, tbl, st, ln, ks, vs: paged_prefill_attention_kernel(
+            q, kp, vp, tbl, st, ln, interpret=False,
+            k_scale=ks if quantized else None,
+            v_scale=vs if quantized else None,
+        ),
+        S((B, T, Kv, G_, hd), jnp.bfloat16), pool, pool,
+        S((B, P), jnp.int32), rows, rows, scale, scale,
+    )
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_attention_compiles(one_chip, direction):
     B, Sq = 2, 2048
