@@ -97,11 +97,12 @@ def paged_prefill_attention(
     axis shards over ``model`` (q axis 2 here), tables / positions stay
     replicated, and no collective runs inside attention.
 
-    This op is also the speculative-decoding verify path
-    (``models.lm.verify_step_paged``): drafts are written to the pool
-    then attended as a T=k+1 "prefill" whose row ``t`` sees
-    ``kpos <= start + t`` — so verify correctness is exactly chunked-
-    prefill correctness, no separate masking code path.
+    The model's one paged forward (``models.lm.paged_forward``) calls this
+    op for every row count: decode at T=1, prefill chunks, and the
+    speculative-decoding verify pass, whose drafts are written to the pool
+    then attended as T=k+1 rows, row ``t`` seeing ``kpos <= start + t`` —
+    so verify correctness is exactly chunked-prefill correctness, no
+    separate masking code path.
     """
     quantized = k_scale is not None
     layer = jnp.int32(0) if layer is None else layer

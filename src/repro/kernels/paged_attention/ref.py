@@ -108,7 +108,7 @@ def paged_prefill_attention_ref(
       start  (B,) int32         absolute position of the chunk's first row
       q_len  (B,) int32         valid rows (1..T); rows >= q_len are padding
                                 (their output is garbage by contract — the
-                                caller masks it; see attention_prefill_paged)
+                                caller masks it; see attention_paged)
 
     Exactness notes: only keys at ``kpos <= qpos`` are read, and every such
     position was written (cached prefix or earlier-in-chunk), so stale data

@@ -1,6 +1,5 @@
 from repro.models.lm import (  # noqa: F401
     decode_step,
-    decode_step_paged,
     forward,
     forward_hidden,
     init_decode_state,
@@ -8,8 +7,7 @@ from repro.models.lm import (  # noqa: F401
     init_params,
     init_params_as,
     loss_fn,
+    paged_forward,
     prefill,
-    prefill_chunk_paged,
-    verify_step_paged,
 )
 from repro.models.runtime import Runtime  # noqa: F401

@@ -285,7 +285,7 @@ def pool_write(pool: jax.Array, pid: jax.Array, slot: jax.Array,
     return pool.at[idx].set(rows.astype(pool.dtype))
 
 
-def attention_prefill_paged(
+def attention_paged(
     p: Params,
     x: jax.Array,
     cache: Dict[str, jax.Array],
@@ -302,22 +302,29 @@ def attention_prefill_paged(
     mesh=None,
     layer=None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One prefill *chunk* per slot against the paged KV pool.
+    """T rows per slot against the paged KV pool: a prefill chunk, a
+    speculative verify pass (T = k + 1) or a decode step (T = 1).
 
-    x: (B, T, d) chunk hidden states; cache: {"kp","vp"} (N, Kv, page, hd),
+    x: (B, T, d) hidden states; cache: {"kp","vp"} (N, Kv, page, hd),
     or all layers' pools stacked (L, N, Kv, page, hd) with ``layer`` the
     one to write and read (in place: the stack is never sliced);
     tables: (B, P) int32; start: (B,) absolute position of x[:, 0]; q_len:
     (B,) valid rows (rows >= q_len are right-padding: their KV goes to the
     null page and their output is zeroed so downstream per-row compute
-    stays deterministic).
+    stays deterministic). A decode step is the call with T = 1,
+    ``start = lengths`` and ``q_len = active``: an inactive slot writes the
+    null page and the kernel walks none of its pages.
 
-    Write-then-attend: the chunk's roped K/V land in their block-table
+    Write-then-attend: the rows' roped K/V land in their block-table
     pages first, then every row attends with the absolute-position causal
     mask ``kpos <= start + t`` — which covers both the cached prefix (pages
     adopted from the radix cache or written by earlier chunks) and
     earlier-in-chunk positions, and never reads allocated-but-unwritten
-    pages. ``attention_decode_paged`` is the T=1 special case of this.
+    pages. Unlike ``attention_decode``'s ring buffer, every slot has its
+    own position, so continuous batching mixes requests at different
+    depths. With ``mesh`` set the attention runs shard_map'd over the
+    ``model`` axis on per-shard head slices (``kernels.paged_attention``)
+    — the tensor-parallel serving path.
     """
     from repro.kernels.paged_attention import paged_prefill_attention
 
@@ -370,83 +377,4 @@ def attention_prefill_paged(
     )
     out = jnp.where(valid[:, :, None, None, None], out, 0)
     out = out.astype(dtype).reshape(B, T, n_heads * head_dim)
-    return out @ p["wo"].astype(dtype), new_cache
-
-
-def attention_decode_paged(
-    p: Params,
-    x: jax.Array,
-    cache: Dict[str, jax.Array],
-    tables: jax.Array,
-    lengths: jax.Array,
-    active: jax.Array,
-    *,
-    n_heads: int,
-    n_kv: int,
-    head_dim: int,
-    theta: float,
-    window: int = 0,
-    use_kernel: bool = False,
-    mesh=None,
-    layer=None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode token per slot against the paged KV pool.
-
-    x: (B, 1, d); cache: {"kp","vp"} ([L,] N, Kv, page, hd) as in
-    ``attention_prefill_paged`` (``layer``); tables: (B, P) int32;
-    lengths: (B,) int32 tokens already cached per slot (the new token's
-    position); active: (B,) bool — inactive slots write to the null page,
-    the kernel walks none of their pages, and their output is garbage by
-    contract (the serve engine discards it).
-
-    Unlike ``attention_decode``'s ring buffer, every slot here has its own
-    position, so continuous batching can mix requests at different depths.
-    With ``mesh`` set the attention itself runs shard_map'd over the
-    ``model`` axis on per-shard head slices (see ``kernels.paged_attention``)
-    — the tensor-parallel serving path.
-    """
-    from repro.kernels.paged_attention import paged_attention
-
-    B = x.shape[0]
-    dtype = x.dtype
-    G = n_heads // n_kv
-    page = cache["kp"].shape[-2]
-
-    pos = lengths[:, None].astype(jnp.int32)                   # (B, 1)
-    q = rope_apply(_split_heads(x @ p["wq"].astype(dtype), n_heads, head_dim),
-                   pos, theta)
-    k_new = rope_apply(_split_heads(x @ p["wk"].astype(dtype), n_kv, head_dim),
-                       pos, theta)
-    v_new = _split_heads(x @ p["wv"].astype(dtype), n_kv, head_dim)
-
-    page_idx = lengths // page
-    in_range = page_idx < tables.shape[1]      # horizon overflow -> null page
-    page_ids = jnp.take_along_axis(
-        tables, jnp.clip(page_idx, 0, tables.shape[1] - 1)[:, None], axis=1
-    )[:, 0]
-    page_ids = jnp.where(active & in_range, page_ids, 0)
-    slot = jnp.where(active & in_range, lengths % page, 0)
-    new_cache = dict(cache)
-    if "ksc" in cache:
-        from repro.kernels.paged_attention import quant
-
-        k_codes, k_sc = quant.kv_quantize(k_new[:, 0], cache["kp"].dtype)
-        v_codes, v_sc = quant.kv_quantize(v_new[:, 0], cache["vp"].dtype)
-        new_cache["kp"] = pool_write(cache["kp"], page_ids, slot, k_codes, layer)
-        new_cache["vp"] = pool_write(cache["vp"], page_ids, slot, v_codes, layer)
-        new_cache["ksc"] = pool_write(cache["ksc"], page_ids, slot, k_sc, layer)
-        new_cache["vsc"] = pool_write(cache["vsc"], page_ids, slot, v_sc, layer)
-        scales = {"k_scale": new_cache["ksc"], "v_scale": new_cache["vsc"]}
-    else:
-        new_cache["kp"] = pool_write(cache["kp"], page_ids, slot, k_new[:, 0], layer)
-        new_cache["vp"] = pool_write(cache["vp"], page_ids, slot, v_new[:, 0], layer)
-        scales = {}
-
-    q = q.reshape(B, n_kv, G, head_dim) * (head_dim ** -0.5)
-    out = paged_attention(
-        q, new_cache["kp"], new_cache["vp"], tables, lengths + 1,
-        active=active, window=window, use_kernel=use_kernel, mesh=mesh,
-        layer=layer, **scales,
-    )
-    out = out.astype(dtype).reshape(B, 1, n_heads * head_dim)
     return out @ p["wo"].astype(dtype), new_cache

@@ -367,112 +367,57 @@ def init_paged_state(
     return jax.jit(build, out_shardings=shardings)()
 
 
-def prefill_chunk_paged(
+def paged_forward(
     cfg: ArchConfig,
     params: Params,
     caches: List[Any],
-    table_row: jax.Array,
+    tables: jax.Array,
     tokens: jax.Array,
     start: jax.Array,
     q_len: jax.Array,
     rt: Runtime,
     max_len: int,
+    *,
+    all_rows: bool = False,
 ) -> Tuple[jax.Array, List[Any]]:
-    """Prefill ONE chunk of one request's prompt into the paged pool.
+    """T token rows per slot through the paged pool: the one forward of
+    paged serving.
 
-    tokens: (T,) int32 chunk token ids (right-padded past ``q_len``);
-    table_row: (P,) the request's block-table row; start: scalar absolute
-    position of tokens[0]; q_len: scalar valid tokens in this chunk.
-    Returns (logits (V,) at the chunk's last valid position, new caches).
-    The logits only matter on the prompt's final chunk (first-token
-    sampling); computing them every chunk keeps one compiled program.
+    tokens: (B, T) int32 at absolute positions ``start[b] ..
+    start[b] + T - 1``; tables: (B, P) block-table rows; q_len: (B,) valid
+    rows per slot (rows past it are right-padding; 0 disables a slot: its
+    rows write the null page). Returns (logits, new caches): each slot's
+    row ``q_len - 1``, (B, V), picked before the head so a long chunk runs
+    a one-row head; or every row, (B, T, V), with ``all_rows``. The caller
+    owns ``lengths``:
 
-    With a cached prefix adopted from the radix cache, the first chunk
-    starts at ``start = cached_tokens`` — the shared prefix is never
-    re-computed (zero prefill FLOPs for it), only attended through the
-    block table.
-    """
-    specs = layer_specs(cfg, seq_len=max_len, long_variant=rt.long_variant)
-    x = embed_apply(params["embed"], tokens[None], rt.dtype)      # (1, T, d)
-    x, caches = stack_mod.stack_prefill_paged(
-        cfg, params["stack"], x, caches, table_row[None],
-        start[None], q_len[None], rt, specs,
-    )
-    x = jax.lax.dynamic_slice_in_dim(x, q_len - 1, 1, axis=1)
-    x = norm_apply(params["final_norm"], x, cfg.norm)
-    logits = logits_apply(params.get("head"), params["embed"], x, cfg.tie_embeddings)
-    return logits[0, 0], caches
-
-
-def decode_step_paged(
-    cfg: ArchConfig,
-    params: Params,
-    state: Dict[str, Any],
-    token: jax.Array,
-    rt: Runtime,
-    max_len: int,
-    active: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One paged decode step; each slot advances at its own position.
-
-    token: (B,) int32. ``active`` masks slots (inactive slots neither write
-    the pool nor advance ``lengths``; their logits are discarded by the
-    caller). Returns (logits (B, V), new state).
-    """
-    specs = layer_specs(cfg, seq_len=max_len, long_variant=rt.long_variant)
-    lengths = state["lengths"]
-    if active is None:
-        active = jnp.ones(lengths.shape, bool)
-    x = embed_apply(params["embed"], token[:, None], rt.dtype)
-    x, caches = stack_mod.stack_decode(
-        cfg, params["stack"], x, state["caches"], lengths, rt, specs,
-        tables=state["tables"], active=active,
-    )
-    x = norm_apply(params["final_norm"], x, cfg.norm)
-    logits = logits_apply(params.get("head"), params["embed"], x, cfg.tie_embeddings)
-    new_state = dict(
-        state, caches=caches, lengths=lengths + active.astype(jnp.int32)
-    )
-    return logits[:, 0], new_state
-
-
-def verify_step_paged(
-    cfg: ArchConfig,
-    params: Params,
-    state: Dict[str, Any],
-    tokens: jax.Array,
-    q_len: jax.Array,
-    rt: Runtime,
-    max_len: int,
-) -> Tuple[jax.Array, Dict[str, Any]]:
-    """Batched multi-token verify pass for speculative decoding.
-
-    tokens: (B, T) int32 — per slot, the pending token followed by the k
-    draft tokens (T = k + 1), sitting at absolute positions
-    ``lengths[b] .. lengths[b] + T - 1``; q_len: (B,) valid rows per slot
-    (0 disables a slot: its rows write the null page and its logits are
-    zeros). Returns (logits (B, T, V), new state).
-
-    This is ``attention_prefill_paged`` at T = k + 1 — the chunked-prefill
-    write-then-attend path — so row t's KV is written before any row
-    attends, and row t attends exactly positions ``kpos <= lengths + t``:
-    the same band a sequential decode step at that position would see.
-    Rows therefore reproduce the sequential greedy decode stream, and
-    rejected rows need no device-side rollback: their KV sits past the
-    committed length, is never attended there, and is overwritten before
-    any future attend. ``lengths`` is NOT advanced — the caller commits
-    the accepted run length (host-side truncation via ``PagePool.truncate``
-    is the pool-accounting half of the rollback).
+    * decode step: T = 1, ``start = lengths``, ``q_len = active``; the
+      caller then advances ``lengths`` by ``active``.
+    * prefill chunk: B = 1, the request's table row. The logits only
+      matter on the prompt's final chunk (first-token sampling). With a
+      cached prefix adopted from the radix cache the first chunk starts at
+      ``start = cached_tokens``, so the shared prefix is only attended.
+    * speculative verify: T = k + 1 rows, the pending token then k drafts,
+      ``all_rows``. Row t attends exactly ``kpos <= start + t`` (write-
+      then-attend, ``attention_paged``) — the band a sequential decode
+      step at that position would see — so rows reproduce the sequential
+      greedy stream, and rejected rows need no device rollback: their KV
+      sits past the committed length, is never attended there, and is
+      overwritten before any later attend. ``PagePool.truncate`` is the
+      host-side half of the rollback.
     """
     specs = layer_specs(cfg, seq_len=max_len, long_variant=rt.long_variant)
     x = embed_apply(params["embed"], tokens, rt.dtype)            # (B, T, d)
-    x, caches = stack_mod.stack_prefill_paged(
-        cfg, params["stack"], x, state["caches"], state["tables"],
-        state["lengths"], q_len, rt, specs,
+    x, caches = stack_mod.stack_paged(
+        cfg, params["stack"], x, caches, tables, start, q_len, rt, specs,
     )
+    if not all_rows and x.shape[1] > 1:   # each slot's row q_len - 1: (B, 1, d)
+        x = jax.vmap(
+            lambda h, i: jax.lax.dynamic_slice_in_dim(h, i, 1)
+        )(x, q_len - 1)
     x = norm_apply(params["final_norm"], x, cfg.norm)
     logits = logits_apply(params.get("head"), params["embed"], x, cfg.tie_embeddings)
-    return logits, dict(state, caches=caches)
+    return (logits if all_rows else logits[:, 0]), caches
 
 
 def decode_step(

@@ -628,9 +628,23 @@ def write_prefill_to_pool(
     return new_pools
 
 
-def _paged_scan(cfg, stack, x, caches, rt, specs, mixer):
-    """Run the stack over paged KV pools, ``mixer(p, h, pools, layer,
-    window) -> (out, pools)`` standing in for each attention layer.
+def stack_paged(
+    cfg: ArchConfig,
+    stack: Params,
+    x: jax.Array,
+    caches: List[Any],
+    tables: jax.Array,
+    start: jax.Array,
+    q_len: jax.Array,
+    rt: Runtime,
+    specs: Tuple[LayerSpec, ...],
+):
+    """T rows per slot through the stack, writing KV into pool pages.
+
+    x: (B, T, d) embedded rows; ``caches`` are page pools (see
+    ``init_stack_pool``); ``tables``/``start``/``q_len`` as in
+    ``attention_paged``. Returns (x, new_caches). Attention-mixer families
+    only (the same families the paged engine serves).
 
     The pools ride the scan CARRY, stacked over the unit repeats, and each
     layer writes and reads its own slice in place (``layer``): carried as
@@ -652,8 +666,12 @@ def _paged_scan(cfg, stack, x, caches, rt, specs, mixer):
                 assert spec.kind in ("attn", "local"), spec.kind
                 bp = unit_p[f"p{j}"]
                 hn = norm_apply(bp["norm1"], h, cfg.norm)
-                out, pools[f"p{j}"] = mixer(
-                    bp["mixer"], hn, pools[f"p{j}"], layer, spec.window
+                out, pools[f"p{j}"] = attn_mod.attention_paged(
+                    bp["mixer"], hn, pools[f"p{j}"], tables, start, q_len,
+                    n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                    head_dim=cfg.head_dim, theta=cfg.rope_theta,
+                    window=spec.window, use_kernel=rt.use_paged_kernel,
+                    mesh=rt.mesh, layer=layer,
                 )
                 h = h + out
                 h, _ = _ffn_apply(cfg, bp, h, rt)
@@ -668,36 +686,6 @@ def _paged_scan(cfg, stack, x, caches, rt, specs, mixer):
     return x, new_caches
 
 
-def stack_prefill_paged(
-    cfg: ArchConfig,
-    stack: Params,
-    x: jax.Array,
-    caches: List[Any],
-    tables: jax.Array,
-    start: jax.Array,
-    q_len: jax.Array,
-    rt: Runtime,
-    specs: Tuple[LayerSpec, ...],
-):
-    """One prefill chunk through the stack, writing KV into pool pages.
-
-    x: (B, T, d) embedded chunk; ``caches`` are page pools (see
-    ``init_stack_pool``); ``tables``/``start``/``q_len`` as in
-    ``attention_prefill_paged``. Returns (x, new_caches). The chunked-
-    prefill sibling of ``stack_decode`` — attention-mixer families only
-    (the same families the paged engine serves).
-    """
-    def mixer(p, h, pools, layer, window):
-        return attn_mod.attention_prefill_paged(
-            p, h, pools, tables, start, q_len,
-            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
-            theta=cfg.rope_theta, window=window,
-            use_kernel=rt.use_paged_kernel, mesh=rt.mesh, layer=layer,
-        )
-
-    return _paged_scan(cfg, stack, x, caches, rt, specs, mixer)
-
-
 def stack_decode(
     cfg: ArchConfig,
     stack: Params,
@@ -706,29 +694,11 @@ def stack_decode(
     t: jax.Array,
     rt: Runtime,
     specs: Tuple[LayerSpec, ...],
-    *,
-    tables: Optional[jax.Array] = None,
-    active: Optional[jax.Array] = None,
 ):
-    """One-token decode. x: (B, 1, d). Returns (x, new_caches).
-
-    Dense mode (``tables is None``): ``t`` is the scalar position shared by
-    the whole batch and ``caches`` are ring buffers / recurrent states.
-    Paged mode: ``caches`` are page pools (see ``init_stack_pool``), ``t`` is
-    the per-slot (B,) lengths vector, and ``tables``/``active`` address the
-    pool — each slot decodes at its own depth (continuous batching).
-    """
-    if tables is not None:
-        def mixer(p, h, pools, layer, window):
-            return attn_mod.attention_decode_paged(
-                p, h, pools, tables, t, active,
-                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                head_dim=cfg.head_dim, theta=cfg.rope_theta, window=window,
-                use_kernel=rt.use_paged_kernel, mesh=rt.mesh, layer=layer,
-            )
-
-        return _paged_scan(cfg, stack, x, caches, rt, specs, mixer)
-
+    """One-token decode over ring buffers / recurrent states. x: (B, 1, d);
+    ``t`` is the scalar position shared by the whole batch. Returns
+    (x, new_caches). The paged pool's decode step is ``stack_paged`` at
+    T = 1."""
     segments = build_segments(cfg, specs)
     new_caches: List[Any] = []
 

@@ -43,8 +43,9 @@ Speculative decoding
 With ``EngineConfig.spec_tokens = k`` a decode tick commits a VARIABLE-
 length token run per slot instead of exactly one token: a drafter proposes
 k tokens per slot (model-free prompt-lookup by default, or a paired small
-same-family model — ``serve.spec``), one batched (k+1)-row pass through the
-paged PREFILL path verifies them (``models.lm.verify_step_paged``), and the
+same-family model — ``serve.spec``), one batched (k+1)-row call of the
+paged forward verifies them (``models.lm.paged_forward`` with every row's
+logits — the same function a decode step calls at T=1), and the
 longest draft prefix matching the target's own argmax chain commits
 together with the verify pass's bonus token — 1..k+1 tokens per slot per
 tick. Greedy acceptance (``temperature`` must be 0) makes every committed
@@ -101,14 +102,8 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.kernels.paged_attention.kernel import walked_blocks
-from repro.models import (
-    Runtime,
-    decode_step_paged,
-    init_paged_state,
-    prefill_chunk_paged,
-)
+from repro.models import Runtime, init_paged_state, paged_forward
 from repro.models.layers import Params
-from repro.models.lm import verify_step_paged
 from repro.models.stack import layer_specs, write_prefill_to_pool
 from repro.serve import dense as dense_mod
 from repro.serve import spec as spec_mod
@@ -810,35 +805,50 @@ class ServeEngine:
         return queued + self.pool.tokens_in_use
 
     def _decode_scan_fn(self):
-        """Traceable body shared by the decode-only and fused chunk fns."""
+        """Traceable body shared by the decode-only and fused chunk fns:
+        ``inner_steps`` T=1 paged forwards, each slot at its own depth."""
         cfg, rt, ecfg = self.cfg, self.rt, self.ecfg
         policy = self._policy
 
         def chunk(params, caches, tables, lengths, remaining, tok, keys, steps):
-            state0 = {"caches": caches, "tables": tables, "lengths": lengths}
-
             def step(carry, _):
-                state, rem, tok, steps = carry
+                caches, lengths, rem, tok, steps = carry
                 active = rem > 0
-                logits, state = decode_step_paged(
-                    cfg, params, state, tok, rt, max_len=ecfg.max_len,
-                    active=active,
+                act = active.astype(jnp.int32)
+                logits, caches = paged_forward(
+                    cfg, params, caches, tables, tok[:, None], lengths, act,
+                    rt, ecfg.max_len,
                 )
                 nxt = policy.sample_slots(logits, keys, steps)
                 emit = jnp.where(active, nxt, -1)
                 tok = jnp.where(active, nxt, tok)
-                act = active.astype(jnp.int32)
-                return (state, rem - act, tok, steps + act), emit
+                return (caches, lengths + act, rem - act, tok, steps + act), emit
 
-            (state, remaining, tok, steps), emits = jax.lax.scan(
-                step, (state0, remaining, tok, steps), None,
+            (caches, lengths, remaining, tok, steps), emits = jax.lax.scan(
+                step, (caches, lengths, remaining, tok, steps), None,
                 length=ecfg.inner_steps,
             )
-            return (
-                state["caches"], state["lengths"], remaining, tok, steps, emits
-            )
+            return caches, lengths, remaining, tok, steps, emits
 
         return chunk
+
+    def _prefill_chunk_fn(self):
+        """Traceable body shared by the fused and prefill-only fns: one
+        prefill chunk of the request seated in ``p_slot``. Returns (caches,
+        logits (V,) at the chunk's last valid row)."""
+        cfg, rt, ecfg = self.cfg, self.rt, self.ecfg
+
+        def prefill_chunk(
+            params, caches, tables, p_tokens, p_slot, p_start, p_len
+        ):
+            row = jax.lax.dynamic_slice_in_dim(tables, p_slot, 1, axis=0)
+            logits, caches = paged_forward(
+                cfg, params, caches, row, p_tokens[None], p_start[None],
+                p_len[None], rt, ecfg.max_len,
+            )
+            return caches, logits[0]
+
+        return prefill_chunk
 
     def _build_chunk_fn(self):
         # caches update in place
@@ -851,19 +861,15 @@ class ServeEngine:
         page sets keep the two halves independent — the chunk writes only
         its own sequence's pages, decode slots read only theirs, and shared
         (adopted) prefix pages are read-only for both."""
-        cfg, rt, ecfg = self.cfg, self.rt, self.ecfg
         decode_scan = self._decode_scan_fn()
+        prefill_chunk = self._prefill_chunk_fn()
 
         def fused(
             params, caches, tables, lengths, remaining, tok, keys, steps,
             p_tokens, p_slot, p_start, p_len,
         ):
-            row = jax.lax.dynamic_index_in_dim(
-                tables, p_slot, 0, keepdims=False
-            )
-            pf_logits, caches = prefill_chunk_paged(
-                cfg, params, caches, row, p_tokens, p_start, p_len, rt,
-                ecfg.max_len,
+            caches, pf_logits = prefill_chunk(
+                params, caches, tables, p_tokens, p_slot, p_start, p_len
             )
             caches, lengths, remaining, tok, steps, emits = decode_scan(
                 params, caches, tables, lengths, remaining, tok, keys, steps
@@ -877,19 +883,7 @@ class ServeEngine:
         idle engine admitting a request should not pay the decode scan —
         this is what makes warm-cache TTFT a real reduction rather than a
         decode-tax trade)."""
-        cfg, rt, ecfg = self.cfg, self.rt, self.ecfg
-
-        def pf_only(params, caches, tables, p_tokens, p_slot, p_start, p_len):
-            row = jax.lax.dynamic_index_in_dim(
-                tables, p_slot, 0, keepdims=False
-            )
-            pf_logits, caches = prefill_chunk_paged(
-                cfg, params, caches, row, p_tokens, p_start, p_len, rt,
-                ecfg.max_len,
-            )
-            return caches, pf_logits
-
-        return jax.jit(pf_only, donate_argnums=(1,))
+        return jax.jit(self._prefill_chunk_fn(), donate_argnums=(1,))
 
     def _build_verify_fn(self):
         """Batched (k+1)-row verify + greedy acceptance, one jitted program.
@@ -908,14 +902,14 @@ class ServeEngine:
         policy = self._policy
 
         def verify(params, caches, tables, lengths, tokens, q_len):
-            state = {"caches": caches, "tables": tables, "lengths": lengths}
-            logits, state = verify_step_paged(
-                cfg, params, state, tokens, q_len, rt, ecfg.max_len
+            logits, caches = paged_forward(
+                cfg, params, caches, tables, tokens, lengths, q_len, rt,
+                ecfg.max_len, all_rows=True,
             )
             g = policy.greedy_tokens(logits)                   # (B, k+1)
             ok = (tokens[:, 1:] == g[:, :-1]).astype(jnp.int32)
             a = jnp.cumprod(ok, axis=1).sum(axis=1)            # (B,)
-            return state["caches"], g, a
+            return caches, g, a
 
         return jax.jit(verify, donate_argnums=(1,))
 
@@ -936,27 +930,26 @@ class ServeEngine:
         policy = SamplingPolicy(temperature=0.0, vocab=dcfg.vocab_size)
 
         def draft(params, caches, tables, d_len, forced, n_forced, active):
-            state0 = {"caches": caches, "tables": tables, "lengths": d_len}
             n_steps = (n_forced - 1) + k
 
             def step(carry, i):
-                state, tok = carry
+                caches, d_len, tok = carry
                 inp = jnp.where(
                     i == 0, forced[:, 0],
                     jnp.where(i < n_forced, forced[:, 1], tok),
                 )
-                act = active & (i < n_steps)
-                logits, state = decode_step_paged(
-                    dcfg, params, state, inp, rt, max_len=ecfg.max_len,
-                    active=act,
+                act = (active & (i < n_steps)).astype(jnp.int32)
+                logits, caches = paged_forward(
+                    dcfg, params, caches, tables, inp[:, None], d_len, act,
+                    rt, ecfg.max_len,
                 )
                 emit = policy.greedy_tokens(logits)
-                return (state, emit), emit
+                return (caches, d_len + act, emit), emit
 
-            (state, _), emits = jax.lax.scan(
-                step, (state0, forced[:, 0]), jnp.arange(k + 1)
+            (caches, _, _), emits = jax.lax.scan(
+                step, (caches, d_len, forced[:, 0]), jnp.arange(k + 1)
             )
-            return state["caches"], emits                      # (k+1, B)
+            return caches, emits                               # (k+1, B)
 
         return jax.jit(draft, donate_argnums=(1,))
 
@@ -1258,7 +1251,7 @@ class ServeEngine:
         decode step's 1 (row 0 alone IS that decode step). Every committed
         token is the target's own argmax, so the stream is token-identical
         to non-speculative greedy decode (and to running alone). Rejected
-        rows need no device rollback (see ``models.lm.verify_step_paged``);
+        rows need no device rollback (see ``models.lm.paged_forward``);
         under the optimistic policy the pool reservation is rewound
         host-side via ``PagePool.truncate``."""
         ecfg = self.ecfg

@@ -1,8 +1,8 @@
 """Speculative-decoding drafters for the paged serve engine.
 
 Two drafter kinds feed ``ServeEngine``'s batched verify pass
-(``models.lm.verify_step_paged`` — the paged-prefill write-then-attend path
-at T = k + 1):
+(``models.lm.paged_forward`` at T = k + 1 with every row's logits — the
+write-then-attend forward that also runs decode steps and prefill chunks):
 
 * ``"ngram"`` (default): model-free prompt-lookup drafting. The k proposed
   tokens are the continuation that followed the most recent earlier

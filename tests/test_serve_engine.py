@@ -457,7 +457,7 @@ def test_quantized_pool_batched_equals_alone(arch_state, name, kv_dtype):
 def _paged_step_logits(cfg, params, prompt, kv_dtype, steps, teacher=None):
     """Admission-path harness: prefill -> write_prefill_to_pool -> paged
     decode steps, returning per-step logits (teacher-forced when given)."""
-    from repro.models import decode_step_paged, init_paged_state, prefill
+    from repro.models import init_paged_state, paged_forward, prefill
     from repro.models.stack import write_prefill_to_pool
 
     rt = RT.replace(kv_dtype=kv_dtype)
@@ -490,9 +490,12 @@ def _paged_step_logits(cfg, params, prompt, kv_dtype, steps, teacher=None):
         tok = (int(jnp.argmax(logs[-1][: cfg.vocab_size]))
                if teacher is None else teacher[i])
         toks.append(tok)
-        lg, state = decode_step_paged(
-            cfg, params, state, jnp.asarray([tok]), rt, max_len
+        lg, state["caches"] = paged_forward(
+            cfg, params, state["caches"], state["tables"],
+            jnp.asarray([[tok]], jnp.int32), state["lengths"],
+            jnp.ones((1,), jnp.int32), rt, max_len,
         )
+        state["lengths"] = state["lengths"] + 1
         logs.append(lg[0])
     return logs, toks
 
