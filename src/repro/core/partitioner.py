@@ -304,7 +304,7 @@ class ParallelPlan:
         ``transient_bytes`` the within-stage backward transient the
         ``remat`` policy controls; ``total_bytes`` = device slots +
         transient is what ``validate(act_budget=...)`` gates on."""
-        from repro.core.pipeline import tick_table
+        from repro.core.pipeline import OP_B_EARLY, runner_ops, tick_table
         from repro.core.stash import normalize_stash
         from repro.roofline.analysis import (
             predicted_pipeline_stash_bytes,
@@ -338,6 +338,7 @@ class ParallelPlan:
             "stash_cot": cot_s != "raw",
             "n_act_slots": table.n_act_slots,
             "n_cot_slots": table.n_cot_slots,
+            "early_backwards": int((runner_ops(table) == OP_B_EARLY).sum()),
             "bytes_per_slot": stash_bytes_per_slot(n_elems, s, itemsize),
             "raw_bytes_per_slot": raw_slot,
             "act_bytes": act,

@@ -27,8 +27,10 @@ Three parts:
    live activations are exactly the schedule's slot count: O(P) for 1F1B vs
    O(M) for GPipe at identical math. Backward recomputes each stage forward
    from its stored stage *input* — per-stage rematerialization (Chen'16,
-   1604.06174) composed with the schedule by construction. GPipe and 1F1B
-   run the identical per-microbatch code in the identical per-stage
+   1604.06174) composed with the schedule by construction. The last stage
+   runs each 1F1B backward at its forward's tick and only sends the
+   cotangent at the table's B tick (``runner_ops``). GPipe and 1F1B run
+   the identical per-microbatch code in the identical per-stage
    accumulation order, so their gradients are bitwise equal — asserted in
    tests/benchmarks.
 
@@ -461,6 +463,12 @@ class TickTable:
     executable schedule (each device computes 2M of n_ticks op slots) and
     must agree with ``simulate(name, P, M, t_fwd=1, t_bwd=1)`` — the bench
     asserts this simulator-vs-executable accounting row.
+
+    The runner executes these ops as the table places them on every stage
+    but the last. Where the last stage's B(m) is the tick right after its
+    F(m) (every microbatch under 1F1B), it runs m's whole backward at the
+    F tick, and the B tick only releases the cotangent it kept
+    (``runner_ops``).
     """
     schedule: str
     n_stages: int
@@ -576,6 +584,42 @@ def tick_table(schedule: str, P: int, M: int) -> TickTable:
     )
 
 
+# what ``pipeline_grads`` does at one (tick, stage); see runner_ops
+OP_IDLE, OP_F, OP_B, OP_B_EARLY, OP_SEND = range(5)
+# the runner's op branch for each: an early backward is OP_B's branch, a
+# send computes nothing
+_BRANCH = np.array([OP_IDLE, OP_F, OP_B, OP_B, OP_IDLE], np.int32)
+
+
+def runner_ops(table: TickTable) -> np.ndarray:
+    """(n_ticks, P) int32: what the runner does at each (tick, stage).
+
+    Every stage but the last takes the table's op: OP_F, OP_B or OP_IDLE.
+    On the last stage a forward feeds nothing but its own backward (its
+    output wraps round the ring into stage 0's trash slot), so where the
+    table puts B(m) on the tick right after F(m) — every microbatch under
+    1F1B, none under GPipe at M > 1 — the F tick runs m's whole backward
+    (OP_B_EARLY: head, loss and vjp, with the forward recomputed inside it
+    as at a B tick) and keeps its input cotangent, and the B tick computes
+    nothing and sends the kept cotangent (OP_SEND). It reaches stage P-2 at
+    the tick the table's ``arr_b`` expects, at most one is pending at a
+    time, and the last stage's backwards keep the order 0..M-1, so every
+    slot, arrival and sum is the table's. Ticks that paired a forward with
+    the other stage's backward now pair backward with backward.
+
+    The count of OP_B_EARLY is the mechanism's counter: M a step under
+    1F1B, 0 under GPipe (M > 1)."""
+    ops = np.where(
+        table.f_mb >= 0, OP_F, np.where(table.b_mb >= 0, OP_B, OP_IDLE)
+    ).astype(np.int32)
+    s = table.n_stages - 1
+    for t in range(table.n_ticks - 1):
+        m = table.f_mb[t, s]
+        if m >= 0 and table.b_mb[t + 1, s] == m:
+            ops[t, s], ops[t + 1, s] = OP_B_EARLY, OP_SEND
+    return ops
+
+
 def pipeline_grads(
     first_fn: Callable,
     stage_fn: Callable,
@@ -601,7 +645,12 @@ def pipeline_grads(
     ``shard_map`` over ``mesh``; the backward is computed by this runner
     (``jax.vjp`` per microbatch, recomputing the stage forward from the
     stored stage input — per-stage remat by construction), NOT by AD through
-    the scan, so live state is exactly the table's slot buffers.
+    the scan, so live state is exactly the table's slot buffers plus one
+    pending cotangent. Each (tick, stage) takes the branch ``runner_ops``
+    gives: the table's op, except that the last stage runs a microbatch's
+    backward at its forward tick when its B tick follows at once (all of
+    1F1B), keeps the input cotangent, and at the table's B tick only sends
+    it — the forward it skips had no reader.
 
     Callables (all executed per device, per microbatch):
       first_fn(shared, mb)    -> x            stage-0 input (e.g. embedding)
@@ -623,9 +672,9 @@ def pipeline_grads(
     Mesh-collective safety: the per-tick op branches contain collectives
     over the ``model`` axis only (manual tensor parallelism inside
     ``stage_fn``). All devices sharing a pipe coordinate run the SAME branch
-    every tick (tables depend only on (tick, stage)), so model-axis groups
-    never diverge across a collective. ``ppermute`` transfers sit outside
-    the branches and run every tick.
+    every tick (``runner_ops`` depends only on (tick, stage)), so model-axis
+    groups never diverge across a collective. ``ppermute`` transfers sit
+    outside the branches and run every tick.
 
     Returns (loss_sum, metrics_sums, stage_grads, shared_grads) as global
     arrays: loss/metrics are summed over microbatches and data shards
@@ -644,7 +693,8 @@ def pipeline_grads(
     grads of the (slightly perturbed) forward that actually ran, and
     1F1B == GPipe bitwise still holds per backend. Cotangent slots stay at
     the native dtype by default (they are consumed the tick after they
-    arrive — compressing them buys little capacity); a backend constructed
+    arrive — compressing them buys little capacity; the pending cotangent
+    is always native, as the one sent is); a backend constructed
     with ``cotangents=True`` (``QuantStash``) routes them through the same
     codec as activation slots, which matters when interleaved schedules
     hold several cotangents live (the remat-vs-compression trade
@@ -677,6 +727,9 @@ def pipeline_grads(
         k: jnp.asarray(getattr(table, k))
         for k in ("f_mb", "f_slot", "b_mb", "b_slot", "b_cot", "arr_f", "arr_b")
     }
+    ops = runner_ops(table)
+    rows["op"] = jnp.asarray(ops)
+    rows["branch"] = jnp.asarray(_BRANCH[ops])
     zero_metrics = jax.tree.map(
         lambda s: jnp.zeros(s.shape, s.dtype), metrics_struct
     )
@@ -713,7 +766,7 @@ def pipeline_grads(
             return (y, aux.astype(jnp.float32) + tail), metrics
 
         def tick(carry, row):
-            act, cot, gacc, sacc, lacc, macc, fwd_in, bwd_in = carry
+            act, cot, gacc, sacc, lacc, macc, pend, fwd_in, bwd_in = carry
             g = {k: row[k][stage] for k in rows}
             # arrivals land before this tick's op reads the buffers (slot
             # writes route through the stash backend; -1 -> trash slot Wa)
@@ -725,7 +778,7 @@ def pipeline_grads(
                 cot = backend.put(cot, cot_w, bwd_in)
             else:
                 cot = cot.at[cot_w].set(bwd_in)
-            opk = jnp.where(g["f_mb"] >= 0, 1, jnp.where(g["b_mb"] >= 0, 2, 0))
+            early = g["op"] == OP_B_EARLY
 
             def idle_op(act, cot, gacc, sacc, lacc, macc):
                 return act, cot, gacc, sacc, lacc, macc, x_zero, x_zero
@@ -751,9 +804,12 @@ def pipeline_grads(
                 return act, cot, gacc, sacc, lacc, macc, y, x_zero
 
             def b_op(act, cot, gacc, sacc, lacc, macc):
-                m = g["b_mb"]
+                # an early backward (the last stage at m's F tick) reads
+                # that tick's microbatch and slot
+                m = jnp.where(early, g["f_mb"], g["b_mb"])
+                slot = jnp.where(early, g["f_slot"], g["b_slot"])
                 x_saved = backend.get(
-                    act, jnp.where(g["b_slot"] >= 0, g["b_slot"], Wa), x_struct
+                    act, jnp.where(slot >= 0, slot, Wa), x_struct
                 )
                 cot_r = jnp.where(g["b_cot"] >= 0, g["b_cot"], Wc)
                 if quant_cot:
@@ -772,11 +828,19 @@ def pipeline_grads(
                 return act, cot, gacc, sacc, lacc + loss, macc, x_zero, dx
 
             act, cot, gacc, sacc, lacc, macc, y_send, dx_send = jax.lax.switch(
-                opk, (idle_op, f_op, b_op), act, cot, gacc, sacc, lacc, macc
+                g["branch"], (idle_op, f_op, b_op),
+                act, cot, gacc, sacc, lacc, macc,
+            )
+            # an early backward's dx waits in ``pend`` for the table's B tick
+            dx_send, pend = (
+                jnp.where(g["op"] == OP_SEND, pend,
+                          jnp.where(early, x_zero, dx_send)),
+                jnp.where(early, dx_send, pend),
             )
             fwd_nxt = jax.lax.ppermute(y_send, axis, fwd_perm)
             bwd_nxt = jax.lax.ppermute(dx_send, axis, bwd_perm)
-            return (act, cot, gacc, sacc, lacc, macc, fwd_nxt, bwd_nxt), None
+            return (act, cot, gacc, sacc, lacc, macc, pend,
+                    fwd_nxt, bwd_nxt), None
 
         zeros_like_tree = lambda t: jax.tree.map(
             lambda a: jnp.zeros(a.shape, a.dtype), t
@@ -791,9 +855,10 @@ def pipeline_grads(
             zero_metrics,
             x_zero,
             x_zero,
+            x_zero,
         )
         carry, _ = jax.lax.scan(tick, carry0, rows)
-        _, _, gacc, sacc, lacc, macc, _, _ = carry
+        _, _, gacc, sacc, lacc, macc, _, _, _ = carry
 
         red = (axis,) + ((data_axis,) if data_axis else ())
         sacc = jax.tree.map(lambda a: jax.lax.psum(a, red), sacc)

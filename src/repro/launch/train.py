@@ -624,6 +624,7 @@ def main() -> None:
         print(f"stash={rep['backend']} bytes/slot={rep['bytes_per_slot']} "
               f"(raw {rep['raw_bytes_per_slot']}) "
               f"act high-water={rep['n_act_slots']} slots "
+              f"early backwards={rep['early_backwards']}/step "
               f"device bytes={rep['device_bytes']} "
               f"host bytes={rep['host_bytes']} "
               f"transient bytes={rep['transient_bytes']} (remat={rep['remat']}) "

@@ -9,8 +9,21 @@ from _subproc import REPO_ROOT, subprocess_env
 
 import pytest
 
-from repro.core.pipeline import SCHEDULES, simulate, tick_table
+import numpy as np
 
+from repro.core.pipeline import (
+    OP_B,
+    OP_B_EARLY,
+    OP_F,
+    OP_IDLE,
+    OP_SEND,
+    SCHEDULES,
+    runner_ops,
+    simulate,
+    tick_table,
+)
+
+TABLE_SHAPES = [(2, 4), (2, 8), (4, 4), (4, 16), (3, 5), (1, 4)]
 
 
 def test_gpipe_bubble_closed_form():
@@ -66,7 +79,7 @@ def test_more_microbatches_shrink_bubble():
 
 # ---------------------------------------------------------------- tick tables
 @pytest.mark.parametrize("sched", ["gpipe", "1f1b"])
-@pytest.mark.parametrize("P,M", [(2, 4), (2, 8), (4, 4), (4, 16), (3, 5), (1, 4)])
+@pytest.mark.parametrize("P,M", TABLE_SHAPES)
 def test_tick_table_matches_simulator(sched, P, M):
     """The executable table IS the simulator schedule: same bubble, and the
     greedy slot allocation reproduces Table 4's peak-activation column."""
@@ -78,6 +91,34 @@ def test_tick_table_matches_simulator(sched, P, M):
     for s in range(P):
         assert sorted(m for m in t.f_mb[:, s] if m >= 0) == list(range(M))
         assert sorted(m for m in t.b_mb[:, s] if m >= 0) == list(range(M))
+
+
+@pytest.mark.parametrize("sched", ["gpipe", "1f1b"])
+@pytest.mark.parametrize("P,M", TABLE_SHAPES)
+def test_runner_ops_follow_table(sched, P, M):
+    """The runner's branch per (tick, stage): the table's op everywhere but
+    on the last stage under 1F1B, where each microbatch's backward runs at
+    its F tick and its B tick only sends the kept cotangent."""
+    t = tick_table(sched, P, M)
+    ops = runner_ops(t)
+    table_ops = np.where(t.f_mb >= 0, OP_F, np.where(t.b_mb >= 0, OP_B, OP_IDLE))
+    assert ops.shape == (t.n_ticks, P)
+    if sched == "gpipe":
+        np.testing.assert_array_equal(ops, table_ops)
+        assert (ops == OP_B_EARLY).sum() == 0
+        return
+    last = P - 1
+    np.testing.assert_array_equal(ops[:, :last], table_ops[:, :last])
+    for m in range(M):
+        (tf,) = np.flatnonzero(t.f_mb[:, last] == m)
+        (tb,) = np.flatnonzero(t.b_mb[:, last] == m)
+        assert ops[tf, last] == OP_B_EARLY and ops[tb, last] == OP_SEND
+        assert tb == tf + 1
+    col = ops[:, last]
+    assert set(col.tolist()) <= {OP_IDLE, OP_B_EARLY, OP_SEND}
+    pending = np.cumsum((col == OP_B_EARLY).astype(int) - (col == OP_SEND))
+    assert pending.min() >= 0 and pending.max() <= 1 and pending[-1] == 0
+    assert (ops == OP_B_EARLY).sum() == M
 
 
 def test_tick_table_1f1b_memory_bound():

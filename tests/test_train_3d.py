@@ -131,6 +131,36 @@ def test_1f1b_matches_gpipe_bitwise():
     )
 
 
+def test_1f1b_matches_gpipe_bitwise_quant_cotangents():
+    """Under an int8 stash that also codes the cotangents, 1F1B (whose last
+    stage runs each backward at its forward tick) stays bitwise GPipe."""
+    run(
+        """
+        PP = 2
+        BATCH = batches(1)[0]
+        outs = {}
+        for sched in ("gpipe", "1f1b"):
+            plan = ParallelPlan(dp=1, tp=2, pp=PP, microbatches=M,
+                                schedule=sched, stash="int8",
+                                stash_cot=True).validate(TINY)
+            mesh = make_train_mesh(1, 2, PP)
+            jitted, (s_struct, b_struct) = build_train_pipeline(
+                TINY.name, mesh, plan, tc, ShapeSpec("t", SEQ, B, "train"))
+            state = put(make_state(TINY, opt, tc), s_struct)
+            new_state, m = jitted(state, put(dict(BATCH), b_struct))
+            outs[sched] = (jax.tree.map(np.asarray, new_state["params"]),
+                           float(m["loss"]), float(m["grad_norm"]))
+        assert outs["gpipe"][1:] == outs["1f1b"][1:], (outs["gpipe"][1:],
+                                                       outs["1f1b"][1:])
+        for a, bb in zip(jax.tree.leaves(outs["gpipe"][0]),
+                         jax.tree.leaves(outs["1f1b"][0])):
+            np.testing.assert_array_equal(a, bb)
+        print("QUANT_COT_BITWISE_OK")
+        """,
+        "QUANT_COT_BITWISE_OK",
+    )
+
+
 def test_checkpoint_reshard_on_load():
     """Save under one plan, restore into another (dp, tp, pp) and into the
     2D trainer; both continue with identical step outputs."""
